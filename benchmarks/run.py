@@ -50,6 +50,8 @@ def main() -> None:
                          "wall seconds, gate rows, metrics-registry "
                          "snapshot) as JSON")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         args.fast = True
 

@@ -22,6 +22,7 @@ import numpy as np
 from repro.core import TriangleEngine
 from repro.core.lftj_jax import _count_chunked
 from repro.data.graphs import rmat_graph
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.straggler import BoxScheduler, fail_worker
 
 
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--mem-frac", type=float, default=0.15)
     args = ap.parse_args()
+    enable_compile_cache()
 
     t0 = time.time()
     src, dst = rmat_graph(args.nodes, args.edges, seed=0)

@@ -70,7 +70,7 @@ from .executor import SliceCache, StreamingExecutor, _pow2
 from .iomodel import BlockDevice
 from .lftj_jax import (SENTINEL, _count_chunked, _count_rows_chunked,
                        _list_chunked, _list_pairs_chunked,
-                       _row_intersect_count, csr_from_edges, orient_edges,
+                       _probe_hits, csr_from_edges, orient_edges,
                        pad_neighbors, pad_neighbors_binned)
 
 BACKENDS = ("auto", "binary", "dense", "pallas", "host", "fused")
@@ -99,6 +99,14 @@ class EngineStats:
     n_pallas_boxes: int = 0
     n_host_boxes: int = 0
     n_fused_boxes: int = 0             # whole box on the fused megakernel
+    # boxes routed to fused/dense that fell outside the lane's envelope
+    # (VMEM budget / one-hot cap) and ran on the pallas or binary lane,
+    # and pallas/binary boxes outside theirs (a row wider than
+    # ``intersect.ops.MAX_K``; a padded slice over the executor's
+    # ``_PADDED_WORDS_CAP``) that ran on the host lane
+    n_fused_fallbacks: int = 0
+    n_dense_fallbacks: int = 0
+    n_padded_fallbacks: int = 0
     # per-box device ledger (kernels/ledger): launches + padded transfer
     # bytes across every kernel lane — the measured basis of the fused
     # kernel's >=10x launch-reduction claim
@@ -468,6 +476,34 @@ def _measure_fused_crossover(nv: int, repeats: int, seed: int) -> float:
             crossover = d
             break
     return crossover
+
+
+def sharded_count_program(mesh, chunk: int):
+    """jit'd ``shard_map`` count over the mesh's "boxes" axis: each shard
+    scans its own renumbered slice ``npad[s]`` and edge lists in
+    ``chunk``-edge steps and returns one int32 partial."""
+
+    @jax.jit
+    @partial(shard_map, mesh=mesh,
+             in_specs=(P("boxes", None, None), P("boxes", None),
+                       P("boxes", None), P("boxes", None)),
+             out_specs=P("boxes"), check_rep=False)
+    def run(npad, eu, ev, ok):
+        npad = npad[0]                      # this shard's local slice
+        n_chunks = eu.shape[1] // chunk
+
+        def body(carry, inp):
+            u, v, valid = inp
+            hit = _probe_hits(npad[u], npad[v]) & (valid[:, None] > 0)
+            return carry + jnp.sum(hit.astype(jnp.int32)), None
+
+        total, _ = jax.lax.scan(
+            body, jnp.int32(0),
+            (eu.reshape(n_chunks, chunk), ev.reshape(n_chunks, chunk),
+             ok.reshape(n_chunks, chunk)))
+        return total.reshape(1)
+
+    return run
 
 
 # ---------------------------------------------------------------------------
@@ -1135,31 +1171,11 @@ class TriangleEngine:
         schedule = self._schedule(edge_lists, boxes=boxes)
         eu_s, ev_s, ok_s, npad_s, _rows = self._shard_slices(
             edge_lists, schedule, pad_multiple=self.chunk, source=source)
-        chunk = self.chunk
-
-        @jax.jit
-        @partial(shard_map, mesh=mesh,
-                 in_specs=(P("boxes", None, None), P("boxes", None),
-                           P("boxes", None), P("boxes", None)),
-                 out_specs=P("boxes"), check_rep=False)
-        def run(npad, eu, ev, ok):
-            npad = npad[0]                      # this shard's local slice
-            n_chunks = eu.shape[1] // chunk
-
-            def body(carry, inp):
-                u, v, valid = inp
-                cnt = jax.vmap(_row_intersect_count)(npad[u], npad[v])
-                return carry + jnp.sum(cnt * valid), None
-
-            total, _ = jax.lax.scan(
-                body, jnp.int32(0),
-                (eu.reshape(n_chunks, chunk), ev.reshape(n_chunks, chunk),
-                 ok.reshape(n_chunks, chunk)))
-            return total.reshape(1)
-
+        run = sharded_count_program(mesh, self.chunk)
         parts = run(jnp.asarray(npad_s), jnp.asarray(eu_s),
                     jnp.asarray(ev_s), jnp.asarray(ok_s))
-        return int(jnp.sum(parts))
+        # int32 per shard on the device, summed exactly on the host
+        return int(np.asarray(parts, dtype=np.int64).sum())
 
     def _binned_layout(self, source=None):
         """(row_bin, bins, bin_pos) for the sharded binned kernels.
@@ -1225,8 +1241,8 @@ class TriangleEngine:
 
             def body(carry, inp):
                 u, v, valid = inp
-                cnt = jax.vmap(_row_intersect_count)(npa[u], npb[v])
-                return carry + jnp.sum(cnt * valid), None
+                hit = _probe_hits(npa[u], npb[v]) & (valid[:, None] > 0)
+                return carry + jnp.sum(hit.astype(jnp.int32)), None
 
             t, _ = jax.lax.scan(
                 body, jnp.int32(0),
@@ -1277,11 +1293,12 @@ class TriangleEngine:
     def list(self, capacity: Optional[int] = None) -> np.ndarray:
         """Enumerate all triangles; returns canonical sorted (m, 3) rows.
 
-        The output buffer is bounded (``capacity`` triangles per shard/box);
-        because the kernels return the *exact* total alongside the buffer,
-        overflow is detected and resolved by rescanning with the capacity
-        doubled until everything fits (counting is cheap relative to
-        materialization, so a rescan costs one extra pass).
+        One device pass per box lists it exactly (``run_list``). The
+        sharded path's per-shard output buffer is bounded (``capacity``
+        triangles); because its kernels return the *exact* total alongside
+        the buffer, overflow is detected and resolved by rescanning with
+        the capacity doubled until everything fits (counting is cheap
+        relative to materialization, so a rescan costs one extra pass).
         """
         if self.tracer is not None:
             with self.tracer.span("engine.list", nv=self.nv,
@@ -1299,7 +1316,7 @@ class TriangleEngine:
         mark = self._io_mark()
         if not self.shard:
             ex = self._make_executor()
-            tris = ex.run_list(boxes, capacity)
+            tris = ex.run_list(boxes)
             self._io_collect(mark)
             return self._canonical(tris)
         staged = self._staged_source()
@@ -1346,6 +1363,11 @@ class TriangleEngine:
         if len(tris) == 0:
             return np.zeros((0, 3), dtype=np.int64)
         tris = np.sort(np.asarray(tris, dtype=np.int64), axis=1)
+        n = int(tris[:, 2].max()) + 1
+        if n <= 1 << 21:
+            # one packed int64 key per row sorts in a single pass
+            key = (tris[:, 0] * n + tris[:, 1]) * n + tris[:, 2]
+            return tris[np.argsort(key)]
         order = np.lexsort((tris[:, 2], tris[:, 1], tris[:, 0]))
         return tris[order]
 

@@ -44,9 +44,10 @@ capped at ``inflight_words`` (the engine sizes this window from its memory
 budget); a single slice's raw words are bounded by the planner's budget
 (plus pinned-row spill boxes), which is the Thm. 10 working-set guarantee.
 
-Device shapes are bucketed (rows to multiples of 64, widths and edge counts
-to powers of two) so the number of distinct jit traces stays logarithmic in
-the graph size instead of linear in the box count.
+Device shapes are bucketed (rows, widths and edge counts to powers of two)
+so the number of distinct jit traces stays logarithmic in the graph size
+instead of linear in the box count: on a TPU each trace is a compile of
+seconds to a minute.
 """
 
 from __future__ import annotations
@@ -63,13 +64,18 @@ import numpy as np
 
 from repro.data.pipeline import Prefetcher
 from repro.kernels import ledger as kernel_ledger
+from repro.kernels.intersect.ops import MAX_K as INTERSECT_MAX_K
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import wrap_stage
 
 from .lftj_jax import (SENTINEL, _count_chunked, _count_rows_chunked,
-                       _list_chunked, pad_neighbors_binned)
+                       _list_hits, pad_neighbors_binned)
 
 _ROW_BUCKET = 64
+# largest padded (rows × K) slice the binary lane ships to the device
+# (512 MiB of int32): a hub row of a minmax-oriented store pads every row
+# of its box to the hub's width
+_PADDED_WORDS_CAP = 1 << 27
 
 
 class BoxQueueCancelled(RuntimeError):
@@ -711,7 +717,7 @@ class StreamingExecutor:
         rows = np.unique(np.concatenate([eu_g, ev_g]))
         deg, vals = _gather_rows(rows, slabs)
         k = _pow2(int(deg.max(initial=1)), lo=8)
-        n_rows = -(-(len(rows) + 1) // _ROW_BUCKET) * _ROW_BUCKET
+        n_rows = _pow2(len(rows) + 1, lo=_ROW_BUCKET)
         eu = np.searchsorted(rows, eu_g).astype(np.int32)
         ev = np.searchsorted(rows, ev_g).astype(np.int32)
         off = np.concatenate([np.zeros(1, np.int64),
@@ -906,15 +912,19 @@ class StreamingExecutor:
              np.searchsorted(rows_y, slc.ev)] = 1.0
         if self.use_pallas_kernels:  # MXU tiling pays off on real hardware
             from repro.kernels.triangle_dense.ops import triangle_count
-            return int(triangle_count(ax, ay, mask, use_pallas=True))
-        return int((mask * (ax @ ay.T)).sum())
+            return int(triangle_count(ax, ay, mask))
+        # entries are exact in f32 (< 2^24 paths); the sum is not
+        return int((mask * (ax @ ay.T)).sum(dtype=np.float64))
 
     def _count_pallas(self, slc: BoxSlice) -> int:
-        from repro.kernels.intersect.ops import intersect_count
-        out = intersect_count(slc.npad[slc.eu], slc.npad[slc.ev],
-                              use_pallas=True,
-                              interpret=not self.use_pallas_kernels)
-        return int(jnp.sum(out))
+        """Per-edge |N(u) ∩ N(v)| on the Pallas rotation kernel, the edge
+        rows gathered straight from the slice's compact CSR in bounded
+        chunks — never the (edges × K) pair of padded matrices at once."""
+        from repro.kernels.intersect.ops import intersect_count_rows
+        return intersect_count_rows(slc.row_off, slc.row_vals, slc.eu,
+                                    slc.row_off, slc.row_vals, slc.ev,
+                                    use_pallas=True,
+                                    interpret=not self.use_pallas_kernels)
 
     def _count_binned_slice(self, slc: BoxSlice) -> int:
         """Per-box degree-binned counting: the out-of-core analogue of the
@@ -1003,6 +1013,11 @@ class StreamingExecutor:
         except FusedUnsupported:
             return None
 
+    def _note_fallback(self, field: str) -> None:
+        if self.stats is not None:
+            with self._stats_lock:
+                setattr(self.stats, field, getattr(self.stats, field) + 1)
+
     def _count_slice(self, slc: BoxSlice) -> int:
         with kernel_ledger.attach(tracer=self.tracer) as kl:
             out, op = self._count_slice_dispatch(slc)
@@ -1032,6 +1047,7 @@ class StreamingExecutor:
             # box outside the fused VMEM envelope: fall back to the
             # staged kernel lane (same launch cadence as before the
             # megakernel existed)
+            self._note_fallback("n_fused_fallbacks")
             be = "pallas" if self.use_pallas_kernels else "binary"
         if be == "dense":
             out = self._count_dense(slc)
@@ -1044,7 +1060,16 @@ class StreamingExecutor:
             # one-hot footprint over the cap: fall back. The box is above
             # the dense crossover, hence inside the pallas mid-band — keep
             # the kernel backend when the platform supports it
+            self._note_fallback("n_dense_fallbacks")
             be = "pallas" if self.use_pallas_kernels else "binary"
+        if (be == "pallas" and slc.pad_shape[1] > INTERSECT_MAX_K) or (
+                be == "binary" and not self.degree_bins
+                and slc.padded_words > _PADDED_WORDS_CAP):
+            # a row wider than the intersect kernel's VMEM envelope, or a
+            # padded slice too big to ship (a hub row of a minmax-oriented
+            # store): the host lane reads the compact CSR at any width
+            self._note_fallback("n_padded_fallbacks")
+            be = "host"
         if self.stats is not None:
             with self._stats_lock:
                 if be == "pallas":
@@ -1065,39 +1090,28 @@ class StreamingExecutor:
         self._note_padding(slc)
         return out, be
 
-    def _list_slice(self, slc: BoxSlice,
-                    capacity: Optional[int]) -> Optional[np.ndarray]:
-        """One box's triangles (global vertex ids), bounded buffer +
-        overflow→rescan. Deterministic per slice, so serial and parallel
-        runs produce identical per-box arrays."""
+    def _list_slice(self, slc: BoxSlice) -> Optional[np.ndarray]:
+        """One box's triangles (global vertex ids), in edge order. The
+        device returns the box's (edges × K) hit mask; the triangles are
+        read off its nonzeros here, so one pass lists the box exactly.
+        Deterministic per slice, so serial and parallel runs produce
+        identical per-box arrays."""
         # listing always runs the intersection path (dense is count-only),
         # so no backend counters are recorded here
-        chunk = min(self.chunk, 1024)
+        chunk = min(self.chunk, _pow2(slc.n_edges, lo=256))
         eu, ev = self._bucket_edges(slc, chunk)
-        chunk = min(chunk, len(eu))
-        cap = _pow2(capacity if capacity is not None
-                    else max(256, slc.n_edges))
-        while True:
-            total, buf = _list_chunked(jnp.asarray(slc.npad),
-                                       jnp.asarray(eu),
-                                       jnp.asarray(ev),
-                                       cap=cap, chunk=chunk)
-            total = int(total)
-            if total <= cap:
-                break
-            if self.stats is not None:
-                with self._stats_lock:
-                    self.stats.n_rescans += 1
-            cap *= 2
+        hits = np.asarray(_list_hits(jnp.asarray(slc.npad), jnp.asarray(eu),
+                                     jnp.asarray(ev), chunk=chunk))
         self._note_padding(slc)
-        if total == 0:
+        e, j = np.nonzero(hits[:slc.n_edges])
+        if len(e) == 0:
             return None
-        tris = np.asarray(buf[:total], dtype=np.int64)
-        tris[:, 0] = slc.rows[tris[:, 0]]   # local -> global ids
-        tris[:, 1] = slc.rows[tris[:, 1]]   # (z is already global)
+        u = slc.eu[e]
+        tris = np.stack([slc.rows[u], slc.rows[slc.ev[e]],
+                         slc.npad[u, j]], axis=1).astype(np.int64)
         device = getattr(self.source, "device", None)
         if device is not None:
-            device.write_words(3 * total)
+            device.write_words(3 * len(tris))
         return tris
 
     # -- async scheduler (workers > 1) ----------------------------------------
@@ -1208,27 +1222,24 @@ class StreamingExecutor:
             pf.close()
         return total
 
-    def run_list(self, boxes, capacity: Optional[int] = None) -> np.ndarray:
+    def run_list(self, boxes) -> np.ndarray:
         """Enumerate triangles across the box stream (global vertex ids).
 
-        Per box, a bounded buffer holds candidates; the kernel returns the
-        exact per-box total alongside, so overflow is resolved by rescanning
-        *that box* at doubled capacity (the engine's overflow→rescan
-        protocol, now box-granular). With ``workers>1`` boxes run on the
-        async scheduler and the per-box arrays concatenate in fixed box
-        order — identical output to the sequential run.
+        Each box is listed in one device pass (``_list_slice``). With
+        ``workers>1`` boxes run on the async scheduler and the per-box
+        arrays concatenate in fixed box order — identical output to the
+        sequential run.
         """
         boxes = list(boxes)
         if self.workers > 1 and len(boxes) > 1:
             parts = self._run_parallel(
-                boxes, lambda slc: self._list_slice(slc, capacity))
+                boxes, self._list_slice)
             parts = [p for p in parts if p is not None]
             if not parts:
                 return np.zeros((0, 3), dtype=np.int64)
             return np.concatenate(parts)
         out: List[np.ndarray] = []
-        lst = wrap_stage(self.tracer, "box.compute",
-                         lambda slc: self._list_slice(slc, capacity))
+        lst = wrap_stage(self.tracer, "box.compute", self._list_slice)
         pf = self._stream(boxes)
         try:
             for slc in pf:

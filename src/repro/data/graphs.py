@@ -58,6 +58,28 @@ def rmat_graph(n_nodes: int, n_edges: int, seed: int = 0,
     return simplify_edges(src, dst)
 
 
+def graph500_edges(scale: int, edge_factor: int = 16, seed: int = 0
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+    """Raw Graph500 Kronecker edge list (Graph500 spec §3): 2^scale
+    vertices, ``edge_factor``·2^scale generated edges, initiator
+    A/B/C = 0.57/0.19/0.19, vertex labels randomly permuted from
+    ``seed``. Self loops and duplicates are kept, as the generator emits
+    them; the edge store's ingest drops both."""
+    rng = np.random.default_rng(seed)
+    n, m = 1 << scale, edge_factor << scale
+    a, b, c = 0.57, 0.19, 0.19
+    ab, c_norm, a_norm = a + b, c / (1.0 - (a + b)), a / (a + b)
+    src = np.zeros(m, dtype=np.int64)
+    dst = np.zeros(m, dtype=np.int64)
+    for bit in range(scale):
+        ii = rng.random(m) > ab
+        jj = rng.random(m) > np.where(ii, c_norm, a_norm)
+        src |= ii.astype(np.int64) << bit
+        dst |= jj.astype(np.int64) << bit
+    perm = rng.permutation(n)
+    return perm[src], perm[dst]
+
+
 def clustered_graph(n_clusters: int, cluster_size: int, seed: int = 0,
                     p_in: float = 0.5) -> Tuple[np.ndarray, np.ndarray]:
     """Triangle-rich planted-partition graph (tests/benchmarks oracle).

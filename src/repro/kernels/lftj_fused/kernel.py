@@ -55,10 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # pltpu provides the VMEM scratch allocator; absent on old CPU wheels
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 SENTINEL = np.iinfo(np.int32).max
 KEY_PAD = -1
@@ -73,8 +70,12 @@ def _gather_rows(v, keys, hi, lo):
     """(T, K) adjacency rows of the per-frontier vertices ``v`` ((T, 1)
     int32) via the exact one-hot MXU gather; absent keys -> SENTINEL."""
     onehot = (keys == v).astype(jnp.float32)            # (T, R)
-    g_hi = jnp.dot(onehot, hi, preferred_element_type=jnp.float32)
-    g_lo = jnp.dot(onehot, lo, preferred_element_type=jnp.float32)
+    # HIGHEST: the halves carry up to 16 significant bits, which a single
+    # bf16 MXU pass (8 bits) would round
+    g_hi = jnp.dot(onehot, hi, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+    g_lo = jnp.dot(onehot, lo, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
     g = (g_hi.astype(jnp.int32) << VAL_SPLIT) | g_lo.astype(jnp.int32)
     present = jnp.sum(onehot, axis=1, keepdims=True) > 0.0
     return jnp.where(present, g, SENTINEL)
@@ -84,18 +85,19 @@ def _member_mask(a, b):
     """Element-of-same-row membership ``a[i, j] in b[i, :]`` for SENTINEL-
     padded sorted rows — ``kernels/intersect``'s rotation probe, widened
     to unequal row widths by broadcasting one rotated column of ``b``
-    against all of ``a`` per step."""
+    against all of ``a`` per step. The hit tile is carried as int32:
+    Mosaic cannot legalize a boolean ``scf.for`` carry."""
     kb = b.shape[1]
 
     def step(_, carry):
         hit, b_rot = carry
         col = b_rot[:, 0:1]
-        hit = hit | ((a == col) & (col != SENTINEL))
+        hit = hit | jnp.where((a == col) & (col != SENTINEL), 1, 0)
         return hit, jnp.roll(b_rot, -1, axis=1)
 
     hit, _ = jax.lax.fori_loop(
-        0, kb, step, (jnp.zeros(a.shape, jnp.bool_), b))
-    return hit
+        0, kb, step, (jnp.zeros(a.shape, jnp.int32), b))
+    return hit != 0
 
 
 def starts_only_depths(n_vars: int,

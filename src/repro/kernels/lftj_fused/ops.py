@@ -35,9 +35,18 @@ from .kernel import (KEY_PAD, MAX_DEPTH, SENTINEL, VAL_SPLIT,
                      build_fused_count, build_fused_list,
                      starts_only_depths)
 
-# padded bytes a compiled kernel may keep VMEM-resident (slices + scratch
-# + working tiles); real TPU VMEM is ~16 MiB per core, leave headroom
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# v5e's scoped-VMEM limit for one Mosaic kernel (the compiler refuses a
+# kernel whose stack exceeds "limit 16.00M"); ``_vmem_bytes`` is an upper
+# estimate of that stack, so the gate admits only kernels that compile
+VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+
+# Mosaic's lane width: the count kernel pads row widths and constant rows
+# to it in interpret mode too, so CPU tests build the compiled layout
+LANES = 128
+
+# frontier tile heights tried, largest first: a box too wide for a
+# 128-row tile may still fit with a shorter one
+TILE_ROWS = (128, 64, 32, 16, 8)
 
 
 def _pow2(n: int, lo: int = 1) -> int:
@@ -98,7 +107,7 @@ def _key_intersection(atom_dims, atom_csrs, depth: int) -> np.ndarray:
     return cand if cand is not None else np.zeros(0, np.int64)
 
 
-def _const_rows(atom_dims, atom_csrs, n_vars: int, interpret: bool,
+def _const_rows(atom_dims, atom_csrs, n_vars: int, lane: int,
                 sublanes: int):
     """One SENTINEL-padded constant candidate row per starts-only depth
     (``sublanes`` > 1 replicates it into a Mosaic-friendly tile). Returns
@@ -106,7 +115,6 @@ def _const_rows(atom_dims, atom_csrs, n_vars: int, interpret: bool,
     result is empty and no kernel needs to launch."""
     from .kernel import starts_only_depths
 
-    lane = 8 if interpret else 128
     rows: List[np.ndarray] = []
     widths: List[int] = []
     for d in starts_only_depths(n_vars, atom_dims):
@@ -141,37 +149,47 @@ def _dense_rows(csr, r: int, k: int) -> np.ndarray:
     return out
 
 
-def _count_arrays(atom_csrs, interpret: bool):
+def _count_widths(atom_csrs) -> Tuple[Tuple[int, int], ...]:
+    """Each atom's padded (R, K) in the count kernel's layout."""
+    return tuple(
+        (_pow2(len(keys), lo=8),
+         _pow2(int(np.diff(np.asarray(off, dtype=np.int64)).max(initial=1)),
+               lo=LANES))
+        for keys, off, _ in atom_csrs)
+
+
+def _count_arrays(atom_csrs, widths):
     """Pad every atom into the count kernel's layout: keys (8, R) int32
     (KEY_PAD-padded, sublane-replicated), hi/lo (R, K) f32 halves."""
-    lane = 8 if interpret else 128
     arrs: List[np.ndarray] = []
-    widths: List[Tuple[int, int]] = []
     in_bytes = 0
-    for csr in atom_csrs:
-        keys, off, _ = csr
-        deg = np.diff(np.asarray(off, dtype=np.int64))
-        r = _pow2(len(keys), lo=8)
-        k = _pow2(int(deg.max(initial=1)), lo=lane)
+    for csr, (r, k) in zip(atom_csrs, widths):
+        keys = csr[0]
         kp = np.full((8, r), KEY_PAD, np.int32)
         kp[:, :len(keys)] = np.asarray(keys, dtype=np.int32)
         dense = _dense_rows(csr, r, k)
         hi = (dense >> VAL_SPLIT).astype(np.float32)
         lo = (dense & ((1 << VAL_SPLIT) - 1)).astype(np.float32)
         arrs += [kp, hi, lo]
-        widths.append((r, k))
         in_bytes += kp.nbytes + hi.nbytes + lo.nbytes
-    return arrs, tuple(widths), in_bytes
+    return arrs, in_bytes
 
 
 def _vmem_bytes(widths, const_widths, n_vars, atom_dims, bt: int) -> int:
-    """Estimated VMEM residency of one compiled grid step."""
+    """Upper estimate of the compiler's scoped-VMEM stack for one kernel.
+
+    Fitted to compiles for a described v5e over triangle and four-clique
+    widths up to (R, K) = (2048, 1024) and ``bt`` in {8, 128}, with no
+    under-estimate: the resident atom slices (keys + hi/lo halves, 1.25x
+    for layout copies), the frontier scratch and constant rows, and a
+    per-tile working set that grows with the one-hot width R and the row
+    width K of every gather (``tests/test_chip_compile.py`` re-checks the
+    gate against the compiler)."""
     from .kernel import starts_only_depths
 
-    total = 0
-    for r, k in widths:
-        total += 8 * r * 4 + 2 * r * k * 4          # keys + hi/lo
-    k_max = max(k for _, k in widths)
+    n_atoms = len(widths)
+    resident = sum(8 * r * 4 + 2 * r * k * 4 for r, k in widths)
+    total = resident * 5 // 4
     by_second = [[] for _ in range(n_vars)]
     for ai, (_, sd) in enumerate(atom_dims):
         by_second[sd].append(ai)
@@ -181,7 +199,9 @@ def _vmem_bytes(widths, const_widths, n_vars, atom_dims, bt: int) -> int:
                        else const_widths[so_depths.index(d)]) * 4
     for kc in const_widths:                         # constant rows
         total += 8 * kc * 4
-    total += 6 * bt * k_max * 4                     # working tiles
+    r_max = max(r for r, _ in widths)
+    k_max = max([k for _, k in widths] + list(const_widths))
+    total += bt * 4 * ((3 * n_atoms + 2) * r_max + (2 * n_atoms + 2) * k_max)
     return total
 
 
@@ -202,14 +222,21 @@ def fused_count(atom_dims: Sequence[Tuple[int, int]],
     if len(c0) == 0:
         return 0
     consts, const_widths = _const_rows(atom_dims, atom_csrs, n_vars,
-                                       interpret, sublanes=8)
+                                       LANES, sublanes=8)
     if consts is None:                  # a starts-only depth is empty
         return 0
-    arrs, widths, in_bytes = _count_arrays(atom_csrs, interpret)
-    bt = min(8 if interpret else 128, _pow2(len(c0), lo=8))
-    if not interpret and _vmem_bytes(widths, const_widths, n_vars,
-                                     atom_dims, bt) > VMEM_BUDGET_BYTES:
-        raise FusedUnsupported("padded box slices exceed the VMEM budget")
+    # gate on the padded widths before padding anything: a hub row can
+    # make the padded slices larger than host memory
+    widths = _count_widths(atom_csrs)
+    bt = min(8 if interpret else TILE_ROWS[0], _pow2(len(c0), lo=8))
+    if not interpret:
+        fits = [t for t in TILE_ROWS if t <= bt and _vmem_bytes(
+            widths, const_widths, n_vars, atom_dims, t) <= VMEM_BUDGET_BYTES]
+        if not fits:
+            raise FusedUnsupported(
+                "padded box slices exceed the VMEM budget")
+        bt = fits[0]
+    arrs, in_bytes = _count_arrays(atom_csrs, widths)
     t = _pow2(len(c0), lo=bt)
     c0p = np.full((t, 1), SENTINEL, np.int32)
     c0p[:len(c0), 0] = c0
@@ -247,7 +274,7 @@ def fused_list(atom_dims: Sequence[Tuple[int, int]],
     """(exact total, first ``min(total, capacity)`` bindings) in ONE
     device invocation. The returned rows are the deterministic prefix of
     the program's fixed traversal order — ``total > capacity`` signals
-    overflow and the caller rescans at doubled capacity (PR-6 contract).
+    overflow and the caller rescans with a capacity that holds ``total``.
     """
     atom_dims = tuple(tuple(d) for d in atom_dims)
     _check(atom_dims, n_vars)
@@ -258,7 +285,7 @@ def fused_list(atom_dims: Sequence[Tuple[int, int]],
     if len(c0) == 0:
         return 0, np.zeros((0, n_vars), np.int64)
     consts, _ = _const_rows(atom_dims, atom_csrs, n_vars,
-                            interpret=True, sublanes=1)
+                            lane=8, sublanes=1)
     if consts is None:                  # a starts-only depth is empty
         return 0, np.zeros((0, n_vars), np.int64)
     arrs, in_bytes = _list_arrays(atom_csrs)

@@ -7,13 +7,16 @@ matmul beats per-edge sorted intersection.
 
 Grid: (nx/bm, ny/bn, d/bk) with the contraction axis innermost so A/B tile
 DMAs double-buffer across k-steps. Each (i,j) cell accumulates
-paths += A_tile @ B_tileᵀ in an fp32 VMEM scratch, then applies the mask
-once at k == nsteps-1 and writes a per-cell scalar partial; the host-side
-wrapper reduces the (nx/bm, ny/bn) partial grid.
+paths += A_tile @ B_tileᵀ in an fp32 VMEM scratch (exact: every entry is
+a path count ≤ d < 2^24), then applies the mask once at k == nsteps-1 and
+folds the masked (bm, bn) tile into an aligned (8, bn) int32 partial by
+summing its 8-row groups. The wrapper sums the (nx/bm·8, ny/bn·bn) partial
+grid on the host in int64, so a box count above 2^24 (or 2^31) stays exact.
 
-VMEM per cell @ (bm,bn,bk)=(128,128,512): (bm·bk + bn·bk + bm·bn + bm·bn)·4B
-≈ 0.63 MiB — far under the ~16 MiB/core VMEM; bk=512 keeps the MXU k-dim
-pipelined at its native 128 multiple.
+VMEM per cell @ (bm,bn,bk)=(128,128,512): 2·(bm·bk + bn·bk + bm·bn)·4B
+(double-buffered inputs) + bm·bn·4B scratch ≈ 1.2 MiB — far under the
+scoped VMEM limit; bk=512 keeps the MXU k-dim pipelined at its native 128
+multiple.
 """
 
 from __future__ import annotations
@@ -24,6 +27,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# sublane height of one partial block: the (8, 128) int32 tile
+PARTIAL_ROWS = 8
 
 
 def _tri_kernel(a_ref, b_ref, m_ref, out_ref, acc_ref, *, nsteps_k):
@@ -41,19 +47,27 @@ def _tri_kernel(a_ref, b_ref, m_ref, out_ref, acc_ref, *, nsteps_k):
 
     @pl.when(k == nsteps_k - 1)
     def _finish():
-        out_ref[0, 0] = jnp.sum(m_ref[...] * acc_ref[...])
+        masked = (m_ref[...] * acc_ref[...]).astype(jnp.int32)
+        bm, bn = masked.shape
+        out_ref[...] = jnp.sum(
+            masked.reshape(bm // PARTIAL_ROWS, PARTIAL_ROWS, bn), axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def triangle_count_pallas(a: jnp.ndarray, b: jnp.ndarray, mask: jnp.ndarray,
-                          bm: int = 128, bn: int = 128, bk: int = 512,
-                          interpret: bool = False) -> jnp.ndarray:
-    """All dims must be multiples of block sizes (ops.py pads). fp32 count."""
+def triangle_partials_pallas(a: jnp.ndarray, b: jnp.ndarray,
+                             mask: jnp.ndarray, bm: int = 128,
+                             bn: int = 128, bk: int = 512,
+                             interpret: bool = False) -> jnp.ndarray:
+    """(nx/bm·8, ny/bn·bn) int32 partial grid whose sum is the count.
+
+    All dims must be multiples of block sizes (ops.py pads); ``bm`` a
+    multiple of 8 and ``bn`` of 128."""
     nx, d = a.shape
     ny = b.shape[0]
     assert nx % bm == 0 and ny % bn == 0 and d % bk == 0, (nx, ny, d, bm, bn, bk)
+    assert bm % PARTIAL_ROWS == 0, bm
     grid = (nx // bm, ny // bn, d // bk)
-    partials = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_tri_kernel, nsteps_k=grid[2]),
         grid=grid,
         in_specs=[
@@ -61,9 +75,9 @@ def triangle_count_pallas(a: jnp.ndarray, b: jnp.ndarray, mask: jnp.ndarray,
             pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
             pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], grid[1]), jnp.float32),
+        out_specs=pl.BlockSpec((PARTIAL_ROWS, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((grid[0] * PARTIAL_ROWS, ny),
+                                       jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(a.astype(jnp.float32), b.astype(jnp.float32), mask.astype(jnp.float32))
-    return jnp.sum(partials)

@@ -1,4 +1,4 @@
-"""jit'd public wrapper for the dense triangle-count kernel (pads + dispatch)."""
+"""Public wrapper for the dense triangle-count kernel (pads + dispatch)."""
 
 from __future__ import annotations
 
@@ -6,8 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import triangle_count_pallas
-from .ref import triangle_count_ref
+from .kernel import triangle_partials_pallas
 
 
 def _pad2(x: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
@@ -19,11 +18,14 @@ def _pad2(x: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
 
 
 def triangle_count(a, b, mask, *, bm: int = 128, bn: int = 128, bk: int = 512,
-                   use_pallas: bool = True, interpret: bool | None = None):
-    """Masked dense triangle count Σ mask ⊙ (A Bᵀ).
+                   interpret: bool | None = None) -> int:
+    """Exact masked dense triangle count Σ mask ⊙ (A Bᵀ) as a Python int,
+    on the Pallas kernel (``ref.triangle_count_ref`` is its reference).
 
     Pads to tile multiples — zero padding is inert (padded rows/cols
-    contribute zero paths and zero mask)."""
+    contribute zero paths and zero mask). Per-entry path counts are exact
+    in f32 (< 2^24); the int32 per-tile partials are summed in int64 on
+    the host."""
     a = jnp.asarray(a)
     b = jnp.asarray(b)
     mask = jnp.asarray(mask)
@@ -37,7 +39,6 @@ def triangle_count(a, b, mask, *, bm: int = 128, bn: int = 128, bk: int = 512,
     a = _pad2(a, nx, d_pad)
     b = _pad2(b, ny, d_pad)
     mask = _pad2(mask, nx, ny)
-    if not use_pallas:
-        return triangle_count_ref(a, b, mask)
-    return triangle_count_pallas(a, b, mask, bm=bm, bn=bn, bk=bk_eff,
-                                 interpret=interpret)
+    partials = triangle_partials_pallas(a, b, mask, bm=bm, bn=bn,
+                                        bk=bk_eff, interpret=interpret)
+    return int(np.asarray(partials, dtype=np.int64).sum())

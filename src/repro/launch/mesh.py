@@ -100,10 +100,11 @@ def maybe_init_distributed(coordinator: Optional[str] = None,
     Configuration comes from the arguments or the environment
     (``REPRO_FABRIC_COORDINATOR``, ``REPRO_FABRIC_NUM_PROCESSES``,
     ``REPRO_FABRIC_PROCESS_ID``). Returns True when the distributed
-    runtime is (or already was) initialized, False when unconfigured or
-    unsupported on this platform — the fabric worker CLI then falls back
-    to file-based partial merging (``fabric.merge_partials``), which needs
-    no cross-process runtime at all."""
+    runtime is (or already was) initialized, False when unconfigured — the
+    fabric worker CLI then falls back to file-based partial merging
+    (``fabric.merge_partials``), which needs no cross-process runtime at
+    all. A configured initialization that fails raises: a worker must not
+    carry on believing it is alone."""
     coordinator = coordinator or os.environ.get("REPRO_FABRIC_COORDINATOR")
     if num_processes is None:
         env = os.environ.get("REPRO_FABRIC_NUM_PROCESSES", "").strip()
@@ -113,16 +114,11 @@ def maybe_init_distributed(coordinator: Optional[str] = None,
         process_id = int(env) if env else None
     if not coordinator or num_processes is None or process_id is None:
         return False
-    try:
+    if not jax.distributed.is_initialized():
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
                                    process_id=process_id)
-        return True
-    except RuntimeError:
-        # already initialized: idempotent success
-        return True
-    except Exception:
-        return False
+    return True
 
 
 # TPU v5e-class hardware constants used by the roofline analysis.

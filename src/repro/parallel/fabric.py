@@ -67,6 +67,7 @@ from repro.core.iomodel import BlockDevice, IOStats
 from repro.core.lftj_jax import csr_from_edges, orient_edges
 from repro.core.queries import Query
 from repro.data.edgestore import EdgeStore, InMemoryEdgeSource
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (FABRIC_AXIS, fabric_mesh,
                                maybe_init_distributed,
                                resolve_fabric_shards)
@@ -75,6 +76,10 @@ from repro.parallel.sharding import (balanced_box_schedule, box_mass_costs_nd,
                                      shard_shipped_ranges)
 from repro.query.executor import QueryEngine, QueryStats
 from repro.query.planner import QueryPlan
+
+
+# bit width of the int32 limbs the mesh count reduction carries
+_LIMB = 24
 
 
 class FabricShippingError(RuntimeError):
@@ -505,8 +510,10 @@ class Fabric:
 
     def _mesh_sum(self, partials: Sequence[int]) -> int:
         """Count reduction as a ``psum`` over the fabric mesh's "shards"
-        axis — one partial per device. int32 lanes: per-shard triangle
-        counts beyond 2^31 need the host reduction."""
+        axis — one partial per device. Device integers are int32, so each
+        partial (below 2^48) travels as 24-bit limbs (high, low) whose
+        sums cannot wrap on a mesh under 2^7 devices; the host recombines
+        them in int64."""
         import jax
         import jax.numpy as jnp
         from jax.experimental.shard_map import shard_map
@@ -514,10 +521,15 @@ class Fabric:
 
         mesh = self.mesh if self.mesh is not None \
             else fabric_mesh(self.n_shards)
-        arr = jnp.asarray(np.asarray(partials, dtype=np.int32))
-        f = shard_map(lambda x: jax.lax.psum(jnp.sum(x), FABRIC_AXIS),
+        p = np.asarray(partials, dtype=np.int64)
+        if p.min(initial=0) < 0 or p.max(initial=0) >= 1 << 48:
+            raise ValueError("fabric partials outside [0, 2^48)")
+        limbs = np.stack([p >> _LIMB, p & ((1 << _LIMB) - 1)], axis=1)
+        f = shard_map(lambda x: jax.lax.psum(jnp.sum(x, axis=0), FABRIC_AXIS),
                       mesh=mesh, in_specs=P(FABRIC_AXIS), out_specs=P())
-        return int(f(arr))
+        hi, lo = np.asarray(f(jnp.asarray(limbs.astype(np.int32))),
+                            dtype=np.int64)
+        return int((hi << _LIMB) + lo)
 
     def count(self, reduce: str = "auto") -> int:
         """Distributed count over this process's shards. ``reduce``:
@@ -644,6 +656,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--out", default=None,
                     help="write the JSON partial here (default: stdout)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.data import graphs
     from repro.query.patterns import PATTERNS

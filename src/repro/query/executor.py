@@ -97,6 +97,8 @@ class QueryStats:
     n_kernel_boxes: int = 0            # innermost pair on kernels/intersect
     n_host_boxes: int = 0              # innermost stage on the host lane
     n_fused_boxes: int = 0             # whole box on the fused megakernel
+    n_fused_fallbacks: int = 0         # fused-routed boxes that ran staged
+    n_kernel_fallbacks: int = 0        # kernel-routed pairs too wide for it
     # per-box device ledger (kernels/ledger): launches + padded transfer
     # bytes across every kernel lane — the measured basis of the fused
     # kernel's >=10x launch-reduction claim
@@ -592,12 +594,19 @@ class QueryEngine:
         with self._stats_lock:
             self.stats.max_frontier = max(self.stats.max_frontier,
                                           vj.max_frontier)
-            if vj.used_fused:
+            if vj.kernel_refused:
+                self.stats.n_kernel_fallbacks += 1
+            if vj.empty:
+                pass                   # empty boxes execute no lane
+            elif vj.used_fused:
                 self.stats.n_fused_boxes += 1
-            elif vj.used_kernel:
-                self.stats.n_kernel_boxes += 1
             else:
-                self.stats.n_host_boxes += 1
+                if vj.device == "fused":
+                    self.stats.n_fused_fallbacks += 1
+                if vj.used_kernel:
+                    self.stats.n_kernel_boxes += 1
+                else:
+                    self.stats.n_host_boxes += 1
             if kl is not None and kl.invocations:
                 self.stats.device_invocations += kl.invocations
                 self.stats.device_transfer_bytes += kl.transfer_bytes
@@ -628,7 +637,7 @@ class QueryEngine:
                    capacity: Optional[int] = None) -> Optional[np.ndarray]:
         """One box's bindings through the bounded buffer: at most ``cap``
         rows are materialized per pass; the join's exact count detects
-        overflow, which rescans *this box* at doubled capacity (the
+        overflow, which rescans *this box* sized to the exact count (the
         triangle executor's box-granular overflow→rescan protocol)."""
         box, bound = built
         cap = capacity
@@ -642,7 +651,7 @@ class QueryEngine:
                     break
                 with self._stats_lock:
                     self.stats.n_rescans += 1
-                cap *= 2
+                cap = _pow2(total)      # the first pass counted exactly
         self._note_join(vj, kl)
         rows = vj.bindings()
         if len(rows) == 0:
@@ -844,7 +853,7 @@ class QueryEngine:
         Per-box result buffers are *bounded*: at most ``capacity`` rows
         materialize per box pass (default derived from ``mem_words`` —
         the output buffer is part of the §5 working set). A box whose
-        exact count exceeds the buffer rescans at doubled capacity
+        exact count exceeds the buffer rescans once, sized to that count
         (``stats.n_rescans``), so results stay complete and deterministic
         while peak result memory respects the budget."""
         results = self.run_boxes("list", capacity)

@@ -223,7 +223,7 @@ class VectorizedBoxJoin:
     ``capacity`` bounds the materialized listing buffer: at most that many
     binding rows are kept (``emitted``), while ``count`` stays the *exact*
     result count — the caller detects overflow from ``count > capacity``
-    and rescans at doubled capacity, exactly the triangle engine's
+    and rescans at a capacity that holds it, exactly the triangle engine's
     overflow→rescan protocol. Emitted rows are always the deterministic
     prefix of the full binding order, so a rescan extends, never reorders
     (the fused lane has its own fixed traversal order with the same
@@ -257,7 +257,9 @@ class VectorizedBoxJoin:
         self.count = 0
         self.emitted = 0
         self.rows_out: List[np.ndarray] = []
+        self.empty = False             # depth 0 had no candidate
         self.used_kernel = False
+        self.kernel_refused = False    # a pair too wide for the kernel
         self.used_fused = False
         self.max_frontier = 0
 
@@ -266,11 +268,12 @@ class VectorizedBoxJoin:
     def run(self):
         """Returns the result count; ``rows_out`` holds the bindings
         (columns in variable order) when ``mode == 'list'``."""
-        if self.device == "fused" and self._run_fused():
-            return self.count
         cand = self._key_intersection(self.by_first[0])
         if len(cand) == 0:
+            self.empty = True          # nothing to run on any lane
             return 0
+        if self.device == "fused" and self._run_fused():
+            return self.count
         self._eval(1, [cand])
         return self.count
 
@@ -412,7 +415,8 @@ class VectorizedBoxJoin:
         if len(live) == 0:
             return
         if len(bound) == 2:
-            if self.kernel_lane:
+            if self.kernel_lane and self._kernel_fits(
+                    a0, pos0[live], a1, pos1[live]):
                 self.count += self._kernel_pair_count(
                     a0, pos0[live], a1, pos1[live])
             else:
@@ -430,6 +434,17 @@ class VectorizedBoxJoin:
                                      cols[ai.first_dim][live][pair_ids])
             mask &= ok_i & member_rows(ai.slc, pos_i, values)
         self.count += int(mask.sum())
+
+    def _kernel_fits(self, a: BoundAtom, pos_a, b: BoundAtom,
+                     pos_b) -> bool:
+        """Whether every row of the pair fits the intersect kernel's
+        VMEM envelope; a wider one (a hub row) is counted on the host."""
+        from repro.kernels.intersect.ops import MAX_K
+
+        fits = max(int(a.slc.deg[pos_a].max(initial=0)),
+                   int(b.slc.deg[pos_b].max(initial=0))) <= MAX_K
+        self.kernel_refused |= not fits
+        return fits
 
     def _kernel_pair_count(self, a: BoundAtom, pos_a,
                            b: BoundAtom, pos_b) -> int:

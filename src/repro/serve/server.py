@@ -281,7 +281,7 @@ class Server:
         if use_pallas_kernels is None:
             import jax
             use_pallas_kernels = jax.default_backend() == "tpu"
-        self._use_pallas = bool(use_pallas_kernels)
+        self.use_pallas_kernels = bool(use_pallas_kernels)
 
         self.device = BlockDevice(
             block_words=io_block_words,
@@ -527,7 +527,7 @@ class Server:
                               workers=h.workers, skew=self.skew,
                               heavy_threshold=self.heavy_threshold,
                               plan=plan0, cancel=h._cancel,
-                              use_pallas_kernels=self._use_pallas,
+                              use_pallas_kernels=self.use_pallas_kernels,
                               tracer=self.tracer, metrics=self.metrics)
             plan = eng.plan()
             with self._lock:
@@ -718,7 +718,7 @@ class Server:
                           device=dev, backend=self.backend,
                           workers=1, skew=self.skew,
                           heavy_threshold=self.heavy_threshold,
-                          use_pallas_kernels=self._use_pallas)
+                          use_pallas_kernels=self.use_pallas_kernels)
         out = eng.count() if mode == "count" else eng.list(capacity)
         return out, eng.stats
 
@@ -768,7 +768,7 @@ class Server:
                          skew=self.skew,
                          heavy_threshold=self.heavy_threshold,
                          device=self.device,
-                         use_pallas_kernels=self._use_pallas,
+                         use_pallas_kernels=self.use_pallas_kernels,
                          tracer=self.tracer, metrics=self.metrics)
             with self.device.attributed(tag):
                 out = fab.count() if mode == "count" else fab.list(capacity)

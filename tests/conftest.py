@@ -1,15 +1,3 @@
-import os
-import sys
-
-try:  # pragma: no cover - prefer the real package when installed
-    import hypothesis  # noqa: F401
-except ImportError:  # fall back to the vendored shim (requirements-dev.txt)
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_compat as _shim
-
-    sys.modules["hypothesis"] = _shim
-    sys.modules["hypothesis.strategies"] = _shim.strategies
-
 import threading
 import time
 

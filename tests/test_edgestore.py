@@ -349,3 +349,42 @@ class TestConcurrentReads:
             total_words += len(v_w)
         # the shared ledger saw exactly the words the threads pulled
         assert dev.stats.word_reads == total_words
+
+
+class TestRowsWiderThanKernel:
+    """A minmax-oriented store keeps a hub's whole neighbor list in one
+    row. A box with a row wider than the intersect kernel's VMEM envelope
+    (``intersect.ops.MAX_K``), or a padded slice over the binary lane's
+    cap, must reach the host lane, counted, on every route that ends at
+    a padded lane — never the kernel, which refuses it."""
+
+    @pytest.fixture(scope="class")
+    def fan(self, tmp_path_factory):
+        from repro.kernels.intersect.ops import MAX_K
+
+        leaves = np.arange(1, MAX_K + 9, dtype=np.int64)
+        src = np.concatenate([np.zeros(len(leaves), np.int64), leaves[:-1]])
+        dst = np.concatenate([leaves, leaves[1:]])
+        path = write_edge_store(tmp_path_factory.mktemp("fan") / "fan.csr",
+                                src, dst)
+        return path, len(leaves) - 1       # one triangle per path edge
+
+    @pytest.mark.parametrize("backend", ["auto", "pallas", "fused"])
+    def test_engine_routes_wide_box_to_host(self, fan, backend):
+        path, want = fan
+        eng = TriangleEngine(store=path, backend=backend,
+                             use_pallas_kernels=True)
+        assert eng.count() == want
+        s = eng.stats
+        assert s.n_padded_fallbacks == s.n_host_boxes == 1
+        assert s.n_pallas_boxes == s.n_binary_boxes == 0
+        assert s.n_fused_fallbacks == (backend == "fused")
+
+    def test_query_engine_routes_wide_pair_to_host(self, fan):
+        from repro.query import QueryEngine, patterns
+
+        path, want = fan
+        qe = QueryEngine(patterns.triangle(), store=path, backend="pallas",
+                         use_pallas_kernels=True)
+        assert qe.count() == want
+        assert qe.stats.n_kernel_fallbacks >= 1

@@ -136,13 +136,39 @@ class TestListing:
 
     def test_overflow_rescan(self):
         """A deliberately tiny buffer must still produce the full, exact
-        listing via the overflow→rescan protocol."""
+        listing via the overflow→rescan protocol (the sharded path's
+        bounded buffer; the box executor lists off a hit mask)."""
         src, dst = complete_graph(12)
-        eng = TriangleEngine(src, dst)
+        eng = TriangleEngine(src, dst, shard=True)
         tris = eng.list(capacity=4)
         assert len(tris) == 12 * 11 * 10 // 6
         assert eng.stats.n_rescans >= 1
         np.testing.assert_array_equal(tris, reference_list(src, dst))
+
+    def test_compare_all_probe_matches_binary_search(self):
+        """The probe compiled for a TPU (compare against the whole row)
+        marks exactly the entries the binary search finds."""
+        import jax.numpy as jnp
+        from repro.core.lftj_jax import (_compare_all_hits, _probe_hits,
+                                         _search_hits)
+        rng = np.random.default_rng(0)
+        rows = []
+        for width in (32, 8):
+            m = np.full((64, width), SENTINEL, np.int32)
+            for i in range(64):
+                r = np.sort(rng.choice(60, rng.integers(0, width + 1),
+                                       replace=False))
+                m[i, :len(r)] = r
+            rows.append(m)
+        a, b = rows
+        want = np.array([[x != SENTINEL and x in b[i] for x in a[i]]
+                         for i in range(len(a))])
+        valid = a != SENTINEL
+        for probe in (_compare_all_hits, _search_hits):
+            got = np.asarray(probe(jnp.asarray(a), jnp.asarray(b)))
+            np.testing.assert_array_equal(got & valid, want)
+        np.testing.assert_array_equal(
+            np.asarray(_probe_hits(jnp.asarray(a), jnp.asarray(b))), want)
 
     def test_list_chunked_total_exact_on_overflow(self):
         import jax.numpy as jnp

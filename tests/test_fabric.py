@@ -305,6 +305,15 @@ class TestMeshReduce:
         # auto picks the mesh when one is attached
         assert fab.count() == oracle("triangle")
 
+    def test_mesh_psum_is_exact_past_int32(self):
+        """Partials beyond 2^31 (a scale-22 Graph500 graph has ~2e9
+        triangles) reduce exactly, not wrapped through int32 lanes."""
+        import jax
+        n = len(jax.devices())
+        fab = small_fabric("triangle", None, mesh=fabric_mesh(n))
+        partials = [3_000_000_007 + i for i in range(n)]
+        assert fab._mesh_sum(partials) == sum(partials)
+
     def test_mesh_reduce_rejects_partial_process(self):
         fab = small_fabric("triangle", 2, process_index=0, n_processes=2)
         with pytest.raises(ValueError, match="n_processes"):

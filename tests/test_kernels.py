@@ -25,7 +25,7 @@ class TestTriangleDense:
         a = (RNG.random((nx, d)) < 0.15).astype(np.float32)
         b = (RNG.random((ny, d)) < 0.15).astype(np.float32)
         m = (RNG.random((nx, ny)) < 0.3).astype(np.float32)
-        got = float(triangle_count(a, b, m, use_pallas=True))
+        got = float(triangle_count(a, b, m))
         want = float(np.sum(m * (a @ b.T)))
         np.testing.assert_allclose(got, want, rtol=1e-5)
 
@@ -43,7 +43,7 @@ class TestTriangleDense:
         a = (RNG.random((96, 256)) < 0.1).astype(np.float32)
         b = (RNG.random((96, 256)) < 0.1).astype(np.float32)
         m = np.ones((96, 96), np.float32)
-        got = float(triangle_count(a, b, m, use_pallas=True))
+        got = float(triangle_count(a, b, m))
         ref = float(triangle_count_ref(jnp.asarray(a), jnp.asarray(b),
                                        jnp.asarray(m)))
         np.testing.assert_allclose(got, ref, rtol=1e-6)

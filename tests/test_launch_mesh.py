@@ -84,6 +84,25 @@ class TestMaybeInitDistributed:
         monkeypatch.setenv("REPRO_FABRIC_COORDINATOR", "127.0.0.1:9999")
         assert maybe_init_distributed() is False
 
+    def test_failed_initialization_raises(self, monkeypatch):
+        """A configured worker whose runtime cannot start must not fall
+        back to running alone."""
+        def refuse(**kw):
+            raise ValueError("coordinator unreachable")
+
+        monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False)
+        monkeypatch.setattr(jax.distributed, "initialize", refuse)
+        with pytest.raises(ValueError, match="unreachable"):
+            maybe_init_distributed("localhost:1", 2, 0)
+
+    def test_already_initialized_is_true(self, monkeypatch):
+        def refuse(**kw):
+            raise AssertionError("initialized twice")
+
+        monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True)
+        monkeypatch.setattr(jax.distributed, "initialize", refuse)
+        assert maybe_init_distributed("localhost:1", 2, 0) is True
+
 
 class TestDryrunFabric:
     def test_import_guard_respects_preset_flags(self, monkeypatch):
@@ -128,3 +147,37 @@ class TestDryrunFabric:
         assert res.returncode == 0, res.stderr[-2000:]
         assert "[OK] fabric__triangle__s2" in res.stdout
         assert (tmp_path / "fabric__triangle__s2.json").exists()
+
+
+class TestCompileCache:
+    FLAGS = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+
+    @pytest.fixture
+    def restore_flags(self):
+        saved = {f: getattr(jax.config, f) for f in self.FLAGS}
+        yield
+        for f, v in saved.items():
+            jax.config.update(f, v)
+
+    def test_env_dir_wins_and_nothing_else_is_set(self, monkeypatch,
+                                                  restore_flags, tmp_path):
+        from repro.launch.compile_cache import enable_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_enable_compilation_cache
+
+    def test_default_is_fixed_checkout_dir(self, monkeypatch,
+                                           restore_flags):
+        from repro.launch.compile_cache import enable_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(os.path.dirname(SRC), ".jax_cache")
+        path = enable_compile_cache()
+        assert os.path.realpath(path) == os.path.realpath(want)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert enable_compile_cache() == path     # same dir every call
