@@ -63,6 +63,7 @@ from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.data.edgestore import EdgeStore, InMemoryEdgeSource
+from repro.obs.trace import maybe_span
 from repro.parallel.sharding import (balanced_box_schedule, box_mesh,
                                      shard_local_slices)
 
@@ -70,7 +71,7 @@ from .executor import SliceCache, StreamingExecutor, _pow2
 from .iomodel import BlockDevice
 from .lftj_jax import (SENTINEL, _count_chunked, _count_rows_chunked,
                        _list_chunked, _list_pairs_chunked,
-                       _probe_hits, csr_from_edges, orient_edges,
+                       _gather_hits, csr_from_edges, orient_edges,
                        pad_neighbors, pad_neighbors_binned)
 
 BACKENDS = ("auto", "binary", "dense", "pallas", "host", "fused")
@@ -488,13 +489,13 @@ def sharded_count_program(mesh, chunk: int):
              in_specs=(P("boxes", None, None), P("boxes", None),
                        P("boxes", None), P("boxes", None)),
              out_specs=P("boxes"), check_rep=False)
-    def run(npad, eu, ev, ok):
+    def sharded_count(npad, eu, ev, ok):
         npad = npad[0]                      # this shard's local slice
         n_chunks = eu.shape[1] // chunk
 
         def body(carry, inp):
             u, v, valid = inp
-            hit = _probe_hits(npad[u], npad[v]) & (valid[:, None] > 0)
+            hit = _gather_hits(npad, npad, u, v)[1] & (valid[:, None] > 0)
             return carry + jnp.sum(hit.astype(jnp.int32)), None
 
         total, _ = jax.lax.scan(
@@ -503,7 +504,7 @@ def sharded_count_program(mesh, chunk: int):
              ok.reshape(n_chunks, chunk)))
         return total.reshape(1)
 
-    return run
+    return sharded_count
 
 
 # ---------------------------------------------------------------------------
@@ -822,12 +823,13 @@ class TriangleEngine:
         resident degree index (``plan_boxes_from_degrees``) so planning
         itself stays out-of-core.
         """
-        if self._plan_cache is not None \
-                and self._plan_cache[0] == self.mem_words:
-            return self._plan_cache[1]
-        boxes = self._plan_uncached()
-        self._plan_cache = (self.mem_words, boxes)
-        return boxes
+        with maybe_span(self.tracer, "engine.plan"):
+            if self._plan_cache is not None \
+                    and self._plan_cache[0] == self.mem_words:
+                return self._plan_cache[1]
+            boxes = self._plan_uncached()
+            self._plan_cache = (self.mem_words, boxes)
+            return boxes
 
     def _plan_uncached(self) -> List[Tuple[int, int, int, int]]:
         if self.nv == 0 or self.source.n_edges == 0:
@@ -894,9 +896,10 @@ class TriangleEngine:
         """
         if self.indices is not None:
             return self.source
-        indptr, indices = self._resident_csr()   # one charged full read
-        return InMemoryEdgeSource(indptr, indices,
-                                  orientation=self.orientation)
+        with maybe_span(self.tracer, "shard.stage"):
+            indptr, indices = self._resident_csr()   # one charged full read
+            return InMemoryEdgeSource(indptr, indices,
+                                      orientation=self.orientation)
 
     def _pick_backend(self, n_edges: int, wx: int, wy: int,
                       box=None) -> str:
@@ -1045,27 +1048,29 @@ class TriangleEngine:
         sparse: List[Tuple[np.ndarray, np.ndarray]] = []
         sparse_boxes: List[Tuple[int, int, int, int]] = []
         heavy: List[Tuple[int, int, int, int]] = []
-        for box in boxes:
-            eu, ev, wx, wy, slab = self._box_edges_full(box, staged)
-            if len(eu) == 0:
-                continue
-            be = self._pick_backend(len(eu), wx, wy, box)
-            if be in ("dense", "pallas"):
-                if self.workers > 1 \
-                        and getattr(staged, "device", None) is None:
-                    # the local heavy boxes consume the same async queue as
-                    # the non-sharded path; only when the staged source is
-                    # uncharged (else the queue's fresh x-slab read would
-                    # double-bill the DMA the slab reuse avoids)
-                    heavy.append(box)
+        with maybe_span(self.tracer, "shard.edges"):
+            for box in boxes:
+                eu, ev, wx, wy, slab = self._box_edges_full(box, staged)
+                if len(eu) == 0:
+                    continue
+                be = self._pick_backend(len(eu), wx, wy, box)
+                if be in ("dense", "pallas"):
+                    if self.workers > 1 \
+                            and getattr(staged, "device", None) is None:
+                        # the local heavy boxes consume the same async
+                        # queue as the non-sharded path; only when the
+                        # staged source is uncharged (else the queue's
+                        # fresh x-slab read would double-bill the DMA the
+                        # slab reuse avoids)
+                        heavy.append(box)
+                    else:
+                        total += ex.count_box(box, x_slab=slab)
                 else:
-                    total += ex.count_box(box, x_slab=slab)
-            else:
-                sparse.append((eu, ev))
-                sparse_boxes.append(box)
-                self.stats.n_binary_boxes += 1
-        if heavy:
-            total += ex.run_count(heavy)
+                    sparse.append((eu, ev))
+                    sparse_boxes.append(box)
+                    self.stats.n_binary_boxes += 1
+            if heavy:
+                total += ex.run_count(heavy)
         if sparse:
             if self.degree_bins:
                 total += self._count_sharded_binned(sparse, staged,
@@ -1167,15 +1172,17 @@ class TriangleEngine:
         """Data-parallel box execution with *non-replicated* neighbor data:
         every shard receives only the renumbered rows its boxes touch, so
         per-device memory is O(slice), not O(V·K)."""
-        mesh = box_mesh(self.devices)
-        schedule = self._schedule(edge_lists, boxes=boxes)
-        eu_s, ev_s, ok_s, npad_s, _rows = self._shard_slices(
-            edge_lists, schedule, pad_multiple=self.chunk, source=source)
-        run = sharded_count_program(mesh, self.chunk)
-        parts = run(jnp.asarray(npad_s), jnp.asarray(eu_s),
-                    jnp.asarray(ev_s), jnp.asarray(ok_s))
-        # int32 per shard on the device, summed exactly on the host
-        return int(np.asarray(parts, dtype=np.int64).sum())
+        with maybe_span(self.tracer, "shard.slices"):
+            schedule = self._schedule(edge_lists, boxes=boxes)
+            eu_s, ev_s, ok_s, npad_s, _rows = self._shard_slices(
+                edge_lists, schedule, pad_multiple=self.chunk,
+                source=source)
+        with maybe_span(self.tracer, "shard.put"):
+            args = [jnp.asarray(x) for x in (npad_s, eu_s, ev_s, ok_s)]
+        with maybe_span(self.tracer, "shard.run"):
+            run = sharded_count_program(box_mesh(self.devices), self.chunk)
+            # int32 per shard on the device, summed exactly on the host
+            return int(np.asarray(run(*args), dtype=np.int64).sum())
 
     def _binned_layout(self, source=None):
         """(row_bin, bins, bin_pos) for the sharded binned kernels.
@@ -1235,13 +1242,14 @@ class TriangleEngine:
                            P("boxes", None), P("boxes", None),
                            P("boxes", None)),
                  out_specs=P("boxes"), check_rep=False)
-        def run(npa, npb, eu, ev, ok):
+        def sharded_count_binned(npa, npb, eu, ev, ok):
             npa, npb = npa[0], npb[0]
             n_chunks = eu.shape[1] // chunk
 
             def body(carry, inp):
                 u, v, valid = inp
-                hit = _probe_hits(npa[u], npb[v]) & (valid[:, None] > 0)
+                hit = _gather_hits(npa, npb, u, v)[1] \
+                    & (valid[:, None] > 0)
                 return carry + jnp.sum(hit.astype(jnp.int32)), None
 
             t, _ = jax.lax.scan(
@@ -1282,9 +1290,9 @@ class TriangleEngine:
                 ev_l[s, :len(ev_s)] = np.searchsorted(vr, ev_s)
                 ok_l[s, :len(eu_s)] = 1
 
-            parts = run(jnp.asarray(npa), jnp.asarray(npb),
-                        jnp.asarray(eu_l), jnp.asarray(ev_l),
-                        jnp.asarray(ok_l))
+            parts = sharded_count_binned(
+                jnp.asarray(npa), jnp.asarray(npb), jnp.asarray(eu_l),
+                jnp.asarray(ev_l), jnp.asarray(ok_l))
             total += int(jnp.sum(parts))
         return total
 
@@ -1311,6 +1319,12 @@ class TriangleEngine:
         return tris
 
     def _list_impl(self, capacity: Optional[int] = None) -> np.ndarray:
+        tris = self._list_rows(capacity)
+        with maybe_span(self.tracer, "engine.canonical"):
+            return self._canonical(tris)
+
+    def _list_rows(self, capacity: Optional[int] = None) -> np.ndarray:
+        """Every triangle once, in no fixed order or orientation."""
         boxes = self.plan()
         self._reset_stats(len(boxes))
         mark = self._io_mark()
@@ -1318,7 +1332,7 @@ class TriangleEngine:
             ex = self._make_executor()
             tris = ex.run_list(boxes)
             self._io_collect(mark)
-            return self._canonical(tris)
+            return tris
         staged = self._staged_source()
         edge_lists = []
         kept_boxes = []
@@ -1340,7 +1354,7 @@ class TriangleEngine:
             tris = self._list_sharded_binned(edge_lists, cap, staged,
                                              boxes=kept_boxes)
             self._io_collect(mark)
-            return self._canonical(tris)
+            return tris
         # the shard slices are identical across capacity rescans: build
         # (and charge) them once, re-run only the kernel on overflow
         mesh = box_mesh(self.devices)
@@ -1356,7 +1370,7 @@ class TriangleEngine:
             self.stats.n_rescans += 1
             cap *= 2
         self._io_collect(mark)
-        return self._canonical(tris)
+        return tris
 
     @staticmethod
     def _canonical(tris: np.ndarray) -> np.ndarray:
@@ -1380,13 +1394,13 @@ class TriangleEngine:
                            P("boxes", None), P("boxes", None)),
                  out_specs=(P("boxes"), P("boxes", None, None)),
                  check_rep=False)
-        def run(npad, eu, ev, ok):
+        def sharded_list(npad, eu, ev, ok):
             total, buf = _list_chunked(npad[0], eu[0], ev[0],
                                        cap=cap, chunk=chunk, valid=ok[0])
             return total.reshape(1), buf.reshape(1, cap, 3)
 
-        totals, bufs = run(jnp.asarray(npad_s), jnp.asarray(eu_s),
-                           jnp.asarray(ev_s), jnp.asarray(ok_s))
+        totals, bufs = sharded_list(jnp.asarray(npad_s), jnp.asarray(eu_s),
+                                    jnp.asarray(ev_s), jnp.asarray(ok_s))
         totals = np.asarray(totals)
         if (totals > cap).any():
             return None, False
@@ -1446,15 +1460,15 @@ class TriangleEngine:
                                P("boxes", None), P("boxes", None)),
                      out_specs=(P("boxes"), P("boxes", None, None)),
                      check_rep=False)
-            def run(npa, npb, eu, ev, us, vs):
+            def sharded_list_binned(npa, npb, eu, ev, us, vs):
                 total, buf = _list_pairs_chunked(
                     npa[0], npb[0], eu[0], ev[0], us[0], vs[0],
                     cap=cap_, chunk=chunk)
                 return total.reshape(1), buf.reshape(1, cap_, 3)
 
-            return run(jnp.asarray(npa), jnp.asarray(npb),
-                       jnp.asarray(eu_l), jnp.asarray(ev_l),
-                       jnp.asarray(us_l), jnp.asarray(vs_l))
+            return sharded_list_binned(
+                jnp.asarray(npa), jnp.asarray(npb), jnp.asarray(eu_l),
+                jnp.asarray(ev_l), jnp.asarray(us_l), jnp.asarray(vs_l))
 
         for (i, j) in sorted(pairs):
             npa_i, npb_j = bins[i][1], bins[j][1]

@@ -66,7 +66,7 @@ from repro.data.pipeline import Prefetcher
 from repro.kernels import ledger as kernel_ledger
 from repro.kernels.intersect.ops import MAX_K as INTERSECT_MAX_K
 from repro.obs import metrics as obs_metrics
-from repro.obs.trace import wrap_stage
+from repro.obs.trace import maybe_span, wrap_iter, wrap_stage
 
 from .lftj_jax import (SENTINEL, _count_chunked, _count_rows_chunked,
                        _list_hits, pad_neighbors_binned)
@@ -790,11 +790,24 @@ class StreamingExecutor:
 
     # -- backends ------------------------------------------------------------
 
+    def _lane_inputs(self, slc: BoxSlice, chunk: int):
+        """The binary lane's padded host inputs, then their device copies:
+        the ``lane.pad`` and ``lane.put`` spans of one box. Returns
+        ``((npad, eu, ev) on the device, bytes shipped)``."""
+        with maybe_span(self.tracer, "lane.pad"):
+            host = (slc.npad,) + self._bucket_edges(slc, chunk)
+        with maybe_span(self.tracer, "lane.put"):
+            dev = tuple(jnp.asarray(x) for x in host)
+        return dev, sum(x.nbytes for x in host)
+
     def _count_binary(self, slc: BoxSlice) -> int:
         chunk = min(self.chunk, _pow2(slc.n_edges, lo=256))
-        eu, ev = self._bucket_edges(slc, chunk)
-        return int(_count_chunked(jnp.asarray(slc.npad), jnp.asarray(eu),
-                                  jnp.asarray(ev), chunk=chunk))
+        (npad, eu, ev), nbytes = self._lane_inputs(slc, chunk)
+        with maybe_span(self.tracer, "lane.run"):
+            total = _count_chunked(npad, eu, ev, chunk=chunk)
+            out = int(total)
+        kernel_ledger.note(1, bytes_in=nbytes, bytes_out=total.dtype.itemsize)
+        return out
 
     def _count_host(self, slc: BoxSlice) -> int:
         """Σ_edges |N(u) ∩ N(v)| on the host, pure numpy.
@@ -1099,20 +1112,21 @@ class StreamingExecutor:
         # listing always runs the intersection path (dense is count-only),
         # so no backend counters are recorded here
         chunk = min(self.chunk, _pow2(slc.n_edges, lo=256))
-        eu, ev = self._bucket_edges(slc, chunk)
-        hits = np.asarray(_list_hits(jnp.asarray(slc.npad), jnp.asarray(eu),
-                                     jnp.asarray(ev), chunk=chunk))
+        (npad, eu, ev), _ = self._lane_inputs(slc, chunk)
+        with maybe_span(self.tracer, "lane.run"):
+            hits = np.asarray(_list_hits(npad, eu, ev, chunk=chunk))
         self._note_padding(slc)
-        e, j = np.nonzero(hits[:slc.n_edges])
-        if len(e) == 0:
-            return None
-        u = slc.eu[e]
-        tris = np.stack([slc.rows[u], slc.rows[slc.ev[e]],
-                         slc.npad[u, j]], axis=1).astype(np.int64)
-        device = getattr(self.source, "device", None)
-        if device is not None:
-            device.write_words(3 * len(tris))
-        return tris
+        with maybe_span(self.tracer, "lane.readout"):
+            e, j = np.nonzero(hits[:slc.n_edges])
+            if len(e) == 0:
+                return None
+            u = slc.eu[e]
+            tris = np.stack([slc.rows[u], slc.rows[slc.ev[e]],
+                             slc.npad[u, j]], axis=1).astype(np.int64)
+            device = getattr(self.source, "device", None)
+            if device is not None:
+                device.write_words(3 * len(tris))
+            return tris
 
     # -- async scheduler (workers > 1) ----------------------------------------
     # The pool/queue machinery itself lives in the module-level
@@ -1211,7 +1225,7 @@ class StreamingExecutor:
         count = wrap_stage(self.tracer, "box.compute", self._count_slice)
         pf = self._stream(boxes)
         try:
-            for slc in pf:
+            for slc in wrap_iter(self.tracer, "box.wait", pf):
                 if slc is None or slc.n_edges == 0:
                     continue
                 self._note(slc)
@@ -1231,26 +1245,24 @@ class StreamingExecutor:
         sequential run.
         """
         boxes = list(boxes)
-        if self.workers > 1 and len(boxes) > 1:
-            parts = self._run_parallel(
-                boxes, self._list_slice)
-            parts = [p for p in parts if p is not None]
-            if not parts:
-                return np.zeros((0, 3), dtype=np.int64)
-            return np.concatenate(parts)
         out: List[np.ndarray] = []
-        lst = wrap_stage(self.tracer, "box.compute", self._list_slice)
-        pf = self._stream(boxes)
-        try:
-            for slc in pf:
-                if slc is None or slc.n_edges == 0:
-                    continue
-                self._note(slc)
-                tris = lst(slc)
-                if tris is not None:
-                    out.append(tris)
-        finally:
-            pf.close()
-        if not out:
-            return np.zeros((0, 3), dtype=np.int64)
-        return np.concatenate(out)
+        if self.workers > 1 and len(boxes) > 1:
+            out = [p for p in self._run_parallel(boxes, self._list_slice)
+                   if p is not None]
+        else:
+            lst = wrap_stage(self.tracer, "box.compute", self._list_slice)
+            pf = self._stream(boxes)
+            try:
+                for slc in wrap_iter(self.tracer, "box.wait", pf):
+                    if slc is None or slc.n_edges == 0:
+                        continue
+                    self._note(slc)
+                    tris = lst(slc)
+                    if tris is not None:
+                        out.append(tris)
+            finally:
+                pf.close()
+        with maybe_span(self.tracer, "list.concat"):
+            if not out:
+                return np.zeros((0, 3), dtype=np.int64)
+            return np.concatenate(out)

@@ -168,6 +168,17 @@ def _probe_hits(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return hit & (a != SENTINEL)
 
 
+def _gather_hits(a_mat: jnp.ndarray, b_mat: jnp.ndarray, u: jnp.ndarray,
+                 v: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(a_mat[u], hit mask of a_mat[u] in b_mat[v])``: the row gathers
+    under the named scope ``gather`` and the probe under ``probe``, so a
+    profile's op metadata says which stage of a program an op is."""
+    with jax.named_scope("gather"):
+        a, b = a_mat[u], b_mat[v]
+    with jax.named_scope("probe"):
+        return a, _probe_hits(a, b)
+
+
 @partial(jax.jit, static_argnames=("chunk",))
 def _count_chunked(npad: jnp.ndarray, eu: jnp.ndarray, ev: jnp.ndarray,
                    chunk: int = 2048) -> jnp.ndarray:
@@ -185,7 +196,7 @@ def _count_chunked(npad: jnp.ndarray, eu: jnp.ndarray, ev: jnp.ndarray,
 
     def body(carry, inp):
         u, v, ok = inp
-        hit = _probe_hits(npad[u], npad[v]) & (ok[:, None] > 0)
+        hit = _gather_hits(npad, npad, u, v)[1] & (ok[:, None] > 0)
         return carry + jnp.sum(hit.astype(carry.dtype)), None
 
     total, _ = jax.lax.scan(body, jnp.int64(0) if jax.config.jax_enable_x64
@@ -255,8 +266,8 @@ def _list_chunked(npad: jnp.ndarray, eu: jnp.ndarray, ev: jnp.ndarray,
     def body(carry, inp):
         total, buf = carry
         u, v, ok = inp
-        a = npad[u]                               # (chunk, K) candidate z's
-        hit = _probe_hits(a, npad[v]) & ok[:, None]
+        a, hit = _gather_hits(npad, npad, u, v)   # a: (chunk, K) z's
+        hit = hit & ok[:, None]
         flat = hit.reshape(-1)
         zs = a.reshape(-1)
         us = jnp.repeat(u, k).astype(jnp.int32)
@@ -285,7 +296,7 @@ def _list_hits(npad: jnp.ndarray, eu: jnp.ndarray, ev: jnp.ndarray,
 
     def body(_, inp):
         u, v = inp
-        return None, _probe_hits(npad[u], npad[v])
+        return None, _gather_hits(npad, npad, u, v)[1]
 
     _, hits = jax.lax.scan(body, None, (eu.reshape(-1, chunk),
                                         ev.reshape(-1, chunk)))
@@ -329,8 +340,7 @@ def _list_pairs_chunked(npa: jnp.ndarray, npb: jnp.ndarray,
     def body(carry, inp):
         total, buf = carry
         u, v, gu, gv = inp
-        a = npa[u]                                # (chunk, ka) candidates
-        hit = _probe_hits(a, npb[v])
+        a, hit = _gather_hits(npa, npb, u, v)     # a: (chunk, ka) z's
         flat = hit.reshape(-1)
         zs = a.reshape(-1)
         gus = jnp.repeat(gu, ka).astype(jnp.int32)

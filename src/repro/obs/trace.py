@@ -6,9 +6,10 @@ constraints, in order:
 
 1. **Zero cost when off.** Instrumented code holds ``self.tracer``
    (``None`` by default) and guards every emission with one attribute
-   check — no wrapper objects, no dummy context managers on the hot
-   path. Attaching a tracer must not change execution order, issue
-   source reads, or touch any ledger: counts, listings and measured
+   check — no per-call wrapper objects on the hot path (``maybe_span``
+   hands back one shared no-op context when the tracer is None).
+   Attaching a tracer must not change execution order, issue source
+   reads, or touch any ledger: counts, listings and measured
    ``block_reads`` are byte-identical traced-on vs traced-off (the CI
    trace-smoke gate).
 2. **Thread-correct nesting.** The span stack is thread-local (the
@@ -27,6 +28,13 @@ records an instant ("i"). ``export_chrome(path)`` writes the standard
 ``trace_event`` JSON (loadable in Perfetto / ``chrome://tracing``);
 ``snapshot()`` returns the raw event dicts for tests.
 
+**One clock.** Every span also enters a ``jax.profiler.TraceAnnotation``
+of the same name on the same thread and leaves it when the span ends, so
+while a ``jax.profiler`` trace is being taken each span lands in the
+trace's host plane on the profiler's own clock, beside the device
+operations (Perfetto, TensorBoard). With no profiler running the
+annotation is a no-op of under a microsecond.
+
 **Lanes.** A fabric run merges shard executions into one trace:
 ``with tracer.lane("shard3"): ...`` assigns every event emitted by the
 current thread to a named lane, exported as its own Chrome *process*
@@ -41,27 +49,30 @@ import json
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from contextlib import nullcontext
+from typing import Dict, Iterable, Iterator, List, Optional
 
-__all__ = ["Tracer", "wrap_stage"]
+__all__ = ["Tracer", "maybe_span", "wrap_iter", "wrap_stage"]
 
 _DEFAULT_CAPACITY = 1 << 16
 
 
 class _Span:
-    """Reusable span context manager (one allocation per span)."""
+    """Reusable span context manager (one allocation per span) holding
+    the span's entered profiler annotation."""
 
-    __slots__ = ("_tracer", "_sid")
+    __slots__ = ("_tracer", "_sid", "_mark")
 
-    def __init__(self, tracer: "Tracer", sid: int):
+    def __init__(self, tracer: "Tracer", sid: int, mark):
         self._tracer = tracer
         self._sid = sid
+        self._mark = mark
 
     def __enter__(self) -> "_Span":
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tracer._end_span(self._sid)
+        self._tracer._end_span(self._sid, self._mark)
         return False
 
 
@@ -87,6 +98,9 @@ class Tracer:
     """Thread-safe span/event recorder with a bounded ring buffer."""
 
     def __init__(self, capacity: int = _DEFAULT_CAPACITY):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
         self.capacity = max(16, int(capacity))
         self._events: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
@@ -117,7 +131,11 @@ class Tracer:
         The begin event is recorded here (monotonic µs, thread id,
         parent span id from this thread's stack); the matching end event
         on exit. ``attrs`` are attached to the begin event's ``args``.
+        A profiler annotation of the same name (no ``attrs``) opens
+        before the begin event and closes after the end event.
         """
+        mark = self._annotation(name)
+        mark.__enter__()
         stack = self._stack()
         sid = next(self._ids)
         rec = {"ph": "B", "name": name, "ts": self._now_us(),
@@ -128,9 +146,9 @@ class Tracer:
             rec["args"] = attrs
         stack.append(sid)
         self._emit(rec)
-        return _Span(self, sid)
+        return _Span(self, sid, mark)
 
-    def _end_span(self, sid: int) -> None:
+    def _end_span(self, sid: int, mark) -> None:
         stack = self._stack()
         # tolerate exception-unwound nesting: pop through to this span
         while stack and stack[-1] != sid:
@@ -140,6 +158,7 @@ class Tracer:
         self._emit({"ph": "E", "ts": self._now_us(),
                     "tid": threading.get_ident(), "sid": sid,
                     "lane": getattr(self._tls, "lane", None)})
+        mark.__exit__(None, None, None)
 
     def event(self, name: str, **attrs) -> None:
         """Record an instant event (cache hit, kernel launch, ...)."""
@@ -242,6 +261,36 @@ def _jsonable(v):
         return int(v)
     except (TypeError, ValueError):
         return repr(v)
+
+
+_NO_SPAN = nullcontext()
+
+
+def maybe_span(tracer: Optional[Tracer], name: str):
+    """``tracer.span(name)``, or one shared no-op context when ``tracer``
+    is None: ``with maybe_span(self.tracer, "lane.pad"): ...`` costs the
+    traced-off path one check and no allocation."""
+    return _NO_SPAN if tracer is None else tracer.span(name)
+
+
+def wrap_iter(tracer: Optional[Tracer], name: str,
+              it: Iterable) -> Iterable:
+    """``it`` itself when ``tracer`` is None; else an iterator over it
+    whose every ``next()`` (the caller blocked on a producer, as on the
+    box pipeline's ``Prefetcher``) runs inside a ``name`` span."""
+    if tracer is None:
+        return it
+    return _spanned_next(tracer, name, iter(it))
+
+
+def _spanned_next(tracer: Tracer, name: str, it: Iterator) -> Iterator:
+    while True:
+        with tracer.span(name):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
 
 
 def wrap_stage(tracer: Optional[Tracer], name: str, fn):

@@ -431,3 +431,198 @@ class TestServeObservability:
             cache = srv.caches["E"]
             hits = int(cache.hits)
             assert labeled_sum(reg, "cache.hits", "tenant") == hits
+
+
+# ---------------------------------------------------------------------------
+# spans inside the lanes, the listing tail and the sharded count path
+# ---------------------------------------------------------------------------
+
+def store_engine(path, tracer=None, **kw):
+    """A store-backed engine over ``GRAPH`` whose plan has several boxes,
+    every one on the binary lane."""
+    src, dst = GRAPH
+    kw.setdefault("backend", "binary")
+    return TriangleEngine.ingest(path, (src, dst), orientation="degree",
+                                 chunk_rows=256, mem_words=4096,
+                                 tracer=tracer, **kw)
+
+
+def parents(tracer):
+    """[(span name, its parent span's name)] of every span, in order."""
+    snap = tracer.snapshot()
+    names = {e["sid"]: e["name"] for e in snap if e["ph"] == "B"}
+    return [(e["name"], names.get(e["parent"])) for e in snap
+            if e["ph"] == "B"]
+
+
+def assert_nested(pairs, child, parent):
+    got = {p for n, p in pairs if n == child}
+    assert got == {parent}, (child, got)
+
+
+class TestLaneSpans:
+    @pytest.fixture(scope="class")
+    def runs(self, tmp_path_factory):
+        """An untraced and a traced engine over the same store, each run
+        through a count and a listing."""
+        d = tmp_path_factory.mktemp("lanes")
+        out = {}
+        for name, tr in (("plain", None), ("traced", Tracer())):
+            eng = store_engine(d / f"{name}.store", tr)
+            count = eng.count()
+            count_stats = eng.stats
+            rows = eng.list()
+            out[name] = dict(tracer=tr, count=count, count_stats=count_stats,
+                             rows=rows, list_stats=eng.stats)
+        return out
+
+    def test_count_opens_lane_spans_inside_each_box(self, runs):
+        tr = runs["traced"]["tracer"]
+        pairs = parents(tr)
+        stats = runs["traced"]["count_stats"]
+        assert stats.n_boxes > 1 and stats.n_binary_boxes > 1
+        for lane in ("lane.pad", "lane.put", "lane.run"):
+            assert_nested(pairs, lane, "box.compute")
+            count_lanes = [n for n, p in pairs[:pairs.index(
+                ("engine.list", None))] if n == lane]
+            assert len(count_lanes) == stats.n_binary_boxes, lane
+        for stage in ("box.compute", "box.wait"):
+            assert {p for n, p in pairs if n == stage} \
+                == {"engine.count", "engine.list"}, stage
+        assert ("engine.plan", "engine.count") in pairs
+
+    def test_lane_spans_follow_each_other_in_a_box(self, runs):
+        snap = runs["traced"]["tracer"].snapshot()
+        order = [e["name"] for e in snap if e["ph"] == "B"
+                 and e["name"].startswith("lane.")]
+        assert order[:3] == ["lane.pad", "lane.put", "lane.run"]
+        assert order[-4:] == ["lane.pad", "lane.put", "lane.run",
+                              "lane.readout"]
+
+    def test_list_opens_readout_concat_and_sort(self, runs):
+        pairs = parents(runs["traced"]["tracer"])
+        assert_nested(pairs, "lane.readout", "box.compute")
+        assert_nested(pairs, "list.concat", "engine.list")
+        assert_nested(pairs, "engine.canonical", "engine.list")
+        assert ("box.wait", "engine.list") in pairs
+        assert ("engine.plan", "engine.list") in pairs
+        readouts = sum(n == "lane.readout" for n, _ in pairs)
+        assert readouts == runs["traced"]["list_stats"].n_streamed_boxes
+
+    def test_fetch_spans_stay_on_the_prefetch_thread(self, runs):
+        """The prefetch thread's spans are unchanged: ``box.fetch`` opens
+        at top level of its own thread, never inside the caller's spans."""
+        pairs = parents(runs["traced"]["tracer"])
+        assert_nested(pairs, "box.fetch", None)
+
+    def test_traced_and_untraced_runs_are_identical(self, runs):
+        plain, traced = runs["plain"], runs["traced"]
+        assert traced["count"] == plain["count"]
+        np.testing.assert_array_equal(traced["rows"], plain["rows"])
+        for key in ("count_stats", "list_stats"):
+            assert traced[key].block_reads == plain[key].block_reads
+            assert traced[key].device_transfer_bytes \
+                == plain[key].device_transfer_bytes
+
+    def test_count_binary_fills_device_transfer_bytes(self, runs):
+        """The binary lane notes one launch per box on the kernel ledger,
+        its padded matrix among the bytes shipped."""
+        stats = runs["plain"]["count_stats"]
+        assert stats.device_invocations == stats.n_binary_boxes
+        assert stats.max_box_device_invocations == 1
+        assert stats.device_transfer_bytes > 4 * stats.padded_words
+
+    def test_count_binary_bytes_per_box(self):
+        from repro.core.executor import StreamingExecutor, _pow2
+        from repro.data.edgestore import InMemoryEdgeSource
+        from repro.kernels import ledger
+
+        src, dst = SMALL
+        eng = TriangleEngine(src, dst, mem_words=4096)
+        stats = EngineStats()
+        ex = StreamingExecutor(InMemoryEdgeSource(eng.indptr, eng.indices),
+                               pick_backend=lambda *a: "binary",
+                               chunk=512, stats=stats)
+        want = 0
+        for box in eng.plan():
+            slc = ex._materialize(box)
+            if slc is None or slc.n_edges == 0:
+                continue
+            with ledger.attach() as kl:
+                ex._count_slice(slc)
+            edges = _pow2(slc.n_edges, lo=256)
+            assert kl.invocations == 1
+            assert kl.bytes_in == slc.npad.nbytes + 2 * 4 * edges
+            assert kl.bytes_out == 4
+            want += kl.transfer_bytes
+        assert want > 0 and stats.device_transfer_bytes == want
+
+
+SHARDED_SPANS = r"""
+import json, tempfile
+from pathlib import Path
+import jax
+from repro.core.engine import TriangleEngine
+from repro.data.graphs import rmat_graph
+from repro.obs import Tracer
+
+assert len(jax.devices()) == 4
+src, dst = rmat_graph(512, 6000, seed=21)
+d = Path(tempfile.mkdtemp())
+out = {}
+for name, tr in (("plain", None), ("traced", Tracer())):
+    eng = TriangleEngine.ingest(d / f"{name}.store", (src, dst),
+                                orientation="degree", chunk_rows=256,
+                                mem_words=4096, backend="binary",
+                                shard=True, tracer=tr)
+    count = eng.count()
+    reads = eng.stats.block_reads
+    edges = eng.stats.shard_edges
+    rows = eng.list()
+    res = {"count": count, "reads": reads, "shard_edges": edges,
+           "list_reads": eng.stats.block_reads,
+           "rows": rows.tolist()}
+    if tr is not None:
+        snap = tr.snapshot()
+        names = {e["sid"]: e["name"] for e in snap if e["ph"] == "B"}
+        res["pairs"] = [[e["name"], names.get(e["parent"])]
+                        for e in snap if e["ph"] == "B"]
+    out[name] = res
+print(json.dumps(out))
+"""
+
+
+def test_sharded_count_spans_on_four_devices():
+    """On four virtual CPU devices a traced sharded ``count()`` opens the
+    five ``shard.*`` spans inside ``engine.count``, in order, and gives
+    the untraced run's count, listing and block reads."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=os.environ.get("XLA_FLAGS", "")
+               + " --xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(root / "src") + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    p = subprocess.run([sys.executable, "-c", SHARDED_SPANS], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    plain, traced = out["plain"], out["traced"]
+    for key in ("count", "reads", "shard_edges", "list_reads", "rows"):
+        assert traced[key] == plain[key], key
+    assert len(plain["shard_edges"]) == 4
+    pairs = [tuple(x) for x in traced["pairs"]]
+    stages = ["shard.stage", "shard.edges", "shard.slices", "shard.put",
+              "shard.run"]
+    in_count = pairs[:pairs.index(("engine.list", None))]
+    assert [n for n, _ in in_count if n.startswith("shard.")] == stages
+    for name in stages:
+        assert (name, "engine.count") in in_count
+    assert ("engine.plan", "engine.count") in in_count
+    # the listing stages its store too, and sorts inside engine.list
+    assert ("shard.stage", "engine.list") in pairs
+    assert ("engine.canonical", "engine.list") in pairs
