@@ -108,6 +108,9 @@ class EngineStats:
     n_fused_fallbacks: int = 0
     n_dense_fallbacks: int = 0
     n_padded_fallbacks: int = 0
+    # listings whose vertex ids need more than 21 bits, so three of them
+    # do not pack into one int64 key: sorted by ``np.lexsort`` instead
+    n_canonical_fallbacks: int = 0
     # per-box device ledger (kernels/ledger): launches + padded transfer
     # bytes across every kernel lane — the measured basis of the fused
     # kernel's >=10x launch-reduction claim
@@ -505,6 +508,51 @@ def sharded_count_program(mesh, chunk: int):
         return total.reshape(1)
 
     return sharded_count
+
+
+_CANONICAL_CHUNK = 1 << 15         # rows per pass of the pack and decode
+
+
+def _sorted_packed_rows(tris: np.ndarray, bits: int) -> np.ndarray:
+    """The rows of ``tris`` as ``(min, mid, max)``, in lexicographic
+    order: a new C-contiguous (m, 3) int64 array, for ids below
+    ``2**bits`` with ``3 * bits <= 63``.
+
+    A min/max network orders each row, the three ids pack into one int64
+    key (``lo << 2*bits | mid << bits | hi``), whose numeric order is the
+    rows' lexicographic order; the keys sort in place and decode by shift
+    and mask straight into the output rows. Packing and decoding go a
+    cache-sized chunk of rows at a time, so the keys and the output are
+    the only full-size arrays."""
+    m, step = len(tris), _CANONICAL_CHUNK
+    key = np.empty(m, np.int64)
+    lo_buf, hi_buf = np.empty(step, np.int64), np.empty(step, np.int64)
+    for s in range(0, m, step):
+        a, b, c = tris[s:s + step].T
+        k = key[s:s + step]
+        lo, hi = lo_buf[:len(k)], hi_buf[:len(k)]
+        np.minimum(a, b, out=lo)
+        np.minimum(lo, c, out=lo)
+        np.maximum(a, b, out=hi)
+        np.maximum(hi, c, out=hi)
+        np.add(a, b, out=k)
+        k += c
+        k -= lo
+        k -= hi                     # the middle id
+        k <<= bits
+        k |= hi
+        lo <<= 2 * bits
+        k |= lo
+    key.sort()
+    mask = (1 << bits) - 1
+    out = np.empty((m, 3), np.int64)
+    for s in range(0, m, step):
+        k, (lo, mid, hi) = key[s:s + step], out[s:s + step].T
+        np.right_shift(k, 2 * bits, out=lo)
+        np.right_shift(k, bits, out=mid)
+        np.bitwise_and(mid, mask, out=mid)
+        np.bitwise_and(k, mask, out=hi)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1301,12 +1349,14 @@ class TriangleEngine:
     def list(self, capacity: Optional[int] = None) -> np.ndarray:
         """Enumerate all triangles; returns canonical sorted (m, 3) rows.
 
-        One device pass per box lists it exactly (``run_list``). The
-        sharded path's per-shard output buffer is bounded (``capacity``
-        triangles); because its kernels return the *exact* total alongside
-        the buffer, overflow is detected and resolved by rescanning with
-        the capacity doubled until everything fits (counting is cheap
-        relative to materialization, so a rescan costs one extra pass).
+        Each row is ``(min, mid, max)`` and the rows are in lexicographic
+        order, as int64 (``_canonical``). One device pass per box lists
+        it exactly (``run_list``). The sharded path's per-shard output
+        buffer is bounded (``capacity`` triangles); because its kernels
+        return the *exact* total alongside the buffer, overflow is
+        detected and resolved by rescanning with the capacity doubled
+        until everything fits (counting is cheap relative to
+        materialization, so a rescan costs one extra pass).
         """
         if self.tracer is not None:
             with self.tracer.span("engine.list", nv=self.nv,
@@ -1319,9 +1369,7 @@ class TriangleEngine:
         return tris
 
     def _list_impl(self, capacity: Optional[int] = None) -> np.ndarray:
-        tris = self._list_rows(capacity)
-        with maybe_span(self.tracer, "engine.canonical"):
-            return self._canonical(tris)
+        return self._canonical(self._list_rows(capacity))
 
     def _list_rows(self, capacity: Optional[int] = None) -> np.ndarray:
         """Every triangle once, in no fixed order or orientation."""
@@ -1372,18 +1420,29 @@ class TriangleEngine:
         self._io_collect(mark)
         return tris
 
-    @staticmethod
-    def _canonical(tris: np.ndarray) -> np.ndarray:
-        if len(tris) == 0:
-            return np.zeros((0, 3), dtype=np.int64)
-        tris = np.sort(np.asarray(tris, dtype=np.int64), axis=1)
-        n = int(tris[:, 2].max()) + 1
-        if n <= 1 << 21:
-            # one packed int64 key per row sorts in a single pass
-            key = (tris[:, 0] * n + tris[:, 1]) * n + tris[:, 2]
-            return tris[np.argsort(key)]
-        order = np.lexsort((tris[:, 2], tris[:, 1], tris[:, 0]))
-        return tris[order]
+    def _canonical(self, tris: np.ndarray) -> np.ndarray:
+        """``tris`` (ids below ``nv``, any row orientation or order) as
+        ``(min, mid, max)`` int64 rows in lexicographic order.
+
+        While the id width ``bits = (nv - 1).bit_length()`` is at most 21,
+        three ids fill at most 63 bits, the non-negative range of one
+        int64 key, and a single in-place sort of the keys orders the rows
+        (``_sorted_packed_rows``): no row sort, argsort or row gather.
+        Wider ids (``nv > 2**21``) take ``np.lexsort`` over the row-sorted
+        columns, counted in ``stats.n_canonical_fallbacks``."""
+        m = len(tris)
+        bits = max(1, (self.nv - 1).bit_length())
+        packed = 3 * bits <= 63
+        with maybe_span(self.tracer, "engine.canonical", rows=m,
+                        packed=packed):
+            if m == 0:
+                return np.zeros((0, 3), dtype=np.int64)
+            if packed:
+                return _sorted_packed_rows(np.asarray(tris), bits)
+            self.stats.n_canonical_fallbacks += 1
+            tris = np.sort(np.asarray(tris, dtype=np.int64), axis=1)
+            order = np.lexsort((tris[:, 2], tris[:, 1], tris[:, 0]))
+            return tris[order]
 
     def _list_sharded(self, slices, cap: int, mesh, chunk: int):
         eu_s, ev_s, ok_s, npad_s, rows_s = slices

@@ -266,11 +266,11 @@ def _jsonable(v):
 _NO_SPAN = nullcontext()
 
 
-def maybe_span(tracer: Optional[Tracer], name: str):
-    """``tracer.span(name)``, or one shared no-op context when ``tracer``
-    is None: ``with maybe_span(self.tracer, "lane.pad"): ...`` costs the
-    traced-off path one check and no allocation."""
-    return _NO_SPAN if tracer is None else tracer.span(name)
+def maybe_span(tracer: Optional[Tracer], name: str, **attrs):
+    """``tracer.span(name, **attrs)``, or one shared no-op context when
+    ``tracer`` is None: ``with maybe_span(self.tracer, "lane.pad"): ...``
+    costs the traced-off path one check."""
+    return _NO_SPAN if tracer is None else tracer.span(name, **attrs)
 
 
 def wrap_iter(tracer: Optional[Tracer], name: str,

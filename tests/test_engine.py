@@ -131,8 +131,10 @@ class TestListing:
         src, dst = er_graph(25, 0.25, seed)
         want = reference_list(src, dst)
         for kw in [dict(), dict(mem_words=150), dict(shard=True)]:
-            got = TriangleEngine(src, dst, **kw).list()
+            eng = TriangleEngine(src, dst, **kw)
+            got = eng.list()
             np.testing.assert_array_equal(got, want), kw
+            assert eng.stats.n_canonical_fallbacks == 0, kw
 
     def test_overflow_rescan(self):
         """A deliberately tiny buffer must still produce the full, exact
