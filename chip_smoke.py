@@ -45,8 +45,9 @@ ROOT = Path(__file__).resolve().parent
 MIN_BOXES = 16          # every store's plan has at least this many boxes
 LANE_FIELDS = ("n_dense_boxes", "n_pallas_boxes", "n_fused_boxes",
                "n_binary_boxes", "n_host_boxes", "n_kernel_boxes",
-               "n_dense_fallbacks", "n_fused_fallbacks",
-               "n_padded_fallbacks", "n_kernel_fallbacks")
+               "n_device_join_boxes", "n_dense_fallbacks",
+               "n_fused_fallbacks", "n_padded_fallbacks",
+               "n_kernel_fallbacks", "n_device_join_fallbacks")
 
 
 class SmokeFailure(AssertionError):
@@ -432,8 +433,10 @@ def run_one_chip(args, workdir: Path, devices, kernels: bool) -> None:
 
     # the small store: same generator at --small-scale. The dense one-hot
     # cap and the fused kernel's VMEM admit only boxes this small, and
-    # its four-clique counts (on the host lane) and the server's solo
-    # reruns fit the run's time limit
+    # the server's solo reruns fit the run's time limit. The four-clique
+    # counts (query phase and server) run on the device join lane; the
+    # query phase's lane mix names n_device_join_boxes and
+    # n_device_join_fallbacks
     small = workdir / "small.store"
     (_, s2, d2), t = timed(lambda: build_store(small, args.small_scale,
                                                args.seed, kernels))

@@ -22,7 +22,8 @@ through the same out-of-core machinery the triangle engine uses:
 * **executing** — ``query.vectorized.VectorizedBoxJoin`` runs the batched
   leapfrog over the per-atom slices (numpy ``searchsorted`` lanes that
   release the GIL; innermost two-variable intersections optionally lower
-  onto the ``kernels/intersect`` Pallas op).
+  onto the ``kernels/intersect`` Pallas op). On a TPU, k-clique count
+  boxes run whole on the device join lane (``query.device_join``).
 * **scheduling** — boxes drain on the shared PR-4 worker pool
   (``core.executor.run_box_queue``) under the same workers=1-oracle
   determinism contract: serialized fetches in queue order, fixed box-order
@@ -51,6 +52,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.executor import (SliceCache, _pow2, merge_queue_telemetry,
@@ -99,6 +101,8 @@ class QueryStats:
     n_fused_boxes: int = 0             # whole box on the fused megakernel
     n_fused_fallbacks: int = 0         # fused-routed boxes that ran staged
     n_kernel_fallbacks: int = 0        # kernel-routed pairs too wide for it
+    n_device_join_boxes: int = 0       # whole count on query.device_join
+    n_device_join_fallbacks: int = 0   # device-join-routed boxes run staged
     # per-box device ledger (kernels/ledger): launches + padded transfer
     # bytes across every kernel lane — the measured basis of the fused
     # kernel's >=10x launch-reduction claim
@@ -193,7 +197,8 @@ class QueryEngine:
     cache_words : per-relation LRU ``SliceCache`` budget (0 disables).
     device : ``core.iomodel.BlockDevice`` charging source reads; defaults
         to a fresh device for store-backed runs, ``None`` in memory.
-    backend : 'auto' (kernel lane on TPU, host lane otherwise), 'host'
+    backend : 'auto' (on TPU the device join lane for k-clique counts
+        and the kernel lane for other boxes; host lane otherwise), 'host'
         (pure numpy), 'pallas' (force the kernels/intersect lowering,
         interpret off-TPU), or 'fused' (force whole-box dispatch to the
         ``kernels/lftj_fused`` megakernel — one device invocation per
@@ -270,9 +275,11 @@ class QueryEngine:
             if inflight_boxes is not None else max(2, 2 * self.workers)
         self.prefetch_depth = max(1, int(prefetch_depth))
         if use_pallas_kernels is None:
-            import jax
             use_pallas_kernels = jax.default_backend() == "tpu"
         self.use_pallas_kernels = bool(use_pallas_kernels)
+        # Pallas kernels compile only for a TPU; elsewhere they interpret
+        self._interpret = not self.use_pallas_kernels \
+            or jax.default_backend() != "tpu"
 
         # -- resolve relation sources ------------------------------------
         rel_names: List[str] = []
@@ -574,20 +581,26 @@ class QueryEngine:
         # back per box to the staged path when outside its envelope;
         # light and mixed boxes are pinned to the host searchsorted lane.
         # backend="fused" forces the fused lane for every box.
+        # On a TPU a uniform plan's count boxes go to the device join
+        # lane (query.device_join) when the pattern is in its envelope;
+        # other boxes run staged, the innermost pair on kernels/intersect.
         fused = self.backend == "fused" or (
             self.backend == "auto" and self.use_pallas_kernels
             and lane == "hub")
         kernel_lane = self.backend == "pallas" or (
             self.backend == "auto" and self.use_pallas_kernels
             and lane not in ("light", "mixed"))
+        xla = (self.backend == "auto" and self.use_pallas_kernels
+               and mode == "count" and lane is None)
         return VectorizedBoxJoin(
             bound, self.n, mode,
             kernel_lane=kernel_lane and mode == "count",
             use_pallas=True,
-            interpret=not self.use_pallas_kernels,
-            device="fused" if fused else "host",
+            interpret=self._interpret,
+            device="fused" if fused else "xla" if xla else "host",
             chunk_entries=self.chunk_entries,
-            capacity=capacity)
+            capacity=capacity,
+            tracer=self.tracer)
 
     def _note_join(self, vj: VectorizedBoxJoin,
                    kl: Optional[kernel_ledger.KernelLedger] = None) -> None:
@@ -600,9 +613,13 @@ class QueryEngine:
                 pass                   # empty boxes execute no lane
             elif vj.used_fused:
                 self.stats.n_fused_boxes += 1
+            elif vj.used_device_join:
+                self.stats.n_device_join_boxes += 1
             else:
                 if vj.device == "fused":
                     self.stats.n_fused_fallbacks += 1
+                if vj.device == "xla":
+                    self.stats.n_device_join_fallbacks += 1
                 if vj.used_kernel:
                     self.stats.n_kernel_boxes += 1
                 else:
@@ -621,6 +638,8 @@ class QueryEngine:
         that actually executed, fallbacks resolved."""
         if vj.used_fused:
             return "fused"
+        if vj.used_device_join:
+            return "device_join"
         if vj.used_kernel:
             return "staged"
         return "host"

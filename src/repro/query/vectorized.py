@@ -22,6 +22,10 @@ Per depth ``d`` of the variable order:
   ``kernels/intersect`` Pallas op on TPU (``intersect_count_rows``) — and
   three or more materialize the pairwise intersection once and filter.
 
+On a TPU a count box of a k-clique skips this machine: ``device="xla"``
+hands the whole box to ``query.device_join`` (XLA programs; the staged
+path stays for every box outside that lane's envelope).
+
 Frontiers are split recursively when the projected expansion exceeds
 ``chunk_entries``, so peak host memory is bounded by the chunk, not the
 result size; splits preserve binding order, keeping counts, listings and
@@ -41,6 +45,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.lftj_jax import SENTINEL
+from repro.obs.trace import maybe_span
 
 
 @dataclass
@@ -213,12 +218,19 @@ class VectorizedBoxJoin:
     TPU, interpret elsewhere) instead of the host ``searchsorted`` lane.
 
     ``device`` picks the box-level lane: ``"host"`` (this module's staged
-    per-level frontier machine) or ``"fused"``, which dispatches the
+    per-level frontier machine), ``"fused"``, which dispatches the
     *whole box* to the ``kernels/lftj_fused`` megakernel — one device
-    invocation per box instead of one per frontier level. Boxes or
-    patterns outside the fused kernel's static envelope (depth bound,
-    unbound intermediate variable, VMEM budget) transparently fall back
-    to the staged path; ``used_fused`` records which lane actually ran.
+    invocation per box instead of one per frontier level — or ``"xla"``
+    (count mode), the k-clique counts of ``query.device_join`` as XLA
+    programs: one padding program and one per row-width class. Boxes or
+    patterns outside the chosen lane's envelope (the fused kernel's depth
+    bound, unbound intermediate variable and VMEM budget; for ``"xla"``
+    a pattern other than a k-clique in its natural order, or a row wider
+    than ``device_join.width_cap``) fall back to the staged path;
+    ``used_fused``/``used_device_join`` record which lane actually ran.
+    ``tracer`` puts the staged path's host work in ``join.host`` spans
+    (the ``kernels/intersect`` calls it makes included) and the xla lane's
+    stages in ``lane.pad``/``lane.put``/``lane.run``.
 
     ``capacity`` bounds the materialized listing buffer: at most that many
     binding rows are kept (``emitted``), while ``count`` stays the *exact*
@@ -237,8 +249,9 @@ class VectorizedBoxJoin:
                  interpret: bool = True,
                  device: str = "host",
                  chunk_entries: int = 4_000_000,
-                 capacity: Optional[int] = None):
-        if device not in ("host", "fused"):
+                 capacity: Optional[int] = None,
+                 tracer=None):
+        if device not in ("host", "fused", "xla"):
             raise ValueError(f"unknown device lane {device!r}")
         self.n = n_vars
         self.mode = mode
@@ -246,6 +259,7 @@ class VectorizedBoxJoin:
         self.use_pallas = use_pallas
         self.interpret = interpret
         self.device = device
+        self.tracer = tracer
         self.chunk_entries = int(chunk_entries)
         self.capacity = None if capacity is None else int(capacity)
         self.atoms = list(atoms)
@@ -261,6 +275,7 @@ class VectorizedBoxJoin:
         self.used_kernel = False
         self.kernel_refused = False    # a pair too wide for the kernel
         self.used_fused = False
+        self.used_device_join = False
         self.max_frontier = 0
 
     # -- public --------------------------------------------------------------
@@ -268,14 +283,35 @@ class VectorizedBoxJoin:
     def run(self):
         """Returns the result count; ``rows_out`` holds the bindings
         (columns in variable order) when ``mode == 'list'``."""
-        cand = self._key_intersection(self.by_first[0])
+        with maybe_span(self.tracer, "join.host"):
+            cand = self._key_intersection(self.by_first[0])
         if len(cand) == 0:
             self.empty = True          # nothing to run on any lane
             return 0
         if self.device == "fused" and self._run_fused():
             return self.count
-        self._eval(1, [cand])
+        if self.device == "xla" and self._run_device_join(cand):
+            return self.count
+        with maybe_span(self.tracer, "join.host"):
+            self._eval(1, [cand])
         return self.count
+
+    def _run_device_join(self, cand: np.ndarray) -> bool:
+        """The box's count on ``query.device_join``; False -> a pattern or
+        a row outside its envelope, and the staged path should run."""
+        from . import device_join
+
+        k = device_join.clique_size(
+            [(a.first_dim, a.second_dim) for a in self.atoms], self.n)
+        if self.mode != "count" or k is None or device_join.box_width(
+                a.slc for a in self.atoms) > device_join.width_cap(k):
+            return False
+        slices = {(a.first_dim, a.second_dim): a.slc for a in self.atoms}
+        self.count = device_join.count_box(slices, k, cand,
+                                           tracer=self.tracer)
+        self.max_frontier = len(cand)
+        self.used_device_join = True
+        return True
 
     def _run_fused(self) -> bool:
         """Whole-box dispatch to the fused megakernel; False -> the box
